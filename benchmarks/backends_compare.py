@@ -25,8 +25,9 @@ from repro.mapreduce import (
 )
 
 # Partition capacity grows ~ tokens/R; the Pallas kernel builds a (C, C)
-# one-hot per partition, so keep this section's corpora modest.
-MAX_TOKENS = 1 << 13
+# one-hot per partition and accepts C <= MAX_C (2048): at 4096 tokens the
+# M = R = 8 partitions are exactly 2048 wide.
+MAX_TOKENS = 1 << 12
 CONFIGS = np.asarray([[8.0, 8.0], [16.0, 16.0]])
 
 

@@ -44,8 +44,9 @@ from repro.telemetry import (
 from repro.telemetry.models import TIME_RESOURCE
 
 #: the conservation cross-check runs every reduce backend; the Pallas
-#: kernel builds a (C, C) one-hot per partition, so keep its corpus tiny.
-CONSERVATION_TOKENS = 1 << 12
+#: kernel accepts partitions up to MAX_C = 2048 wide, which is what the
+#: (8, 8) partitions at capacity factor 8 come to at this size.
+CONSERVATION_TOKENS = 1 << 11
 
 
 class TracedRunner:

@@ -31,12 +31,14 @@ from repro.cluster import (
     generate_workload,
     get_policy,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.core.predictor import ModelDatabase
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--real", action="store_true",
                 help="wall-clock the live MapReduce engine (tiny trace)")
 args = ap.parse_args()
+enable_compile_cache()
 
 # --- the cluster and its workload ------------------------------------------
 if args.real:

@@ -9,8 +9,11 @@ execution time of unseen configurations.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ModelDatabase, fit, grid, profile_experiments
 from repro.mapreduce import JobConfig, build_job, wordcount, wordcount_corpus
+
+enable_compile_cache()
 
 # --- the application (black box to the modeling pipeline) -----------------
 corpus = wordcount_corpus(1 << 15, vocab_size=2048, seed=0)

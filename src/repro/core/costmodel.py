@@ -189,9 +189,7 @@ def roofline_from_compiled(
     hlo_text: str | None = None,
 ) -> RooflineReport:
     """Derive the three roofline terms from a jax Compiled object."""
-    from repro.compat import cost_analysis_dict
-
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     # cost_analysis is per-device for SPMD-partitioned modules.
     flops = float(cost.get("flops", 0.0))
     hbm_bytes = float(cost.get("bytes accessed", 0.0))

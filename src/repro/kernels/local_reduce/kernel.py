@@ -5,81 +5,62 @@ runs *and* front-pack the aggregates, so the combined row can be
 truncated to the task's distinct-key bound before it reaches the shuffle
 fabric.
 
-TPU adaptation: like ``segment_reduce``, the scatter-style segment sum
-becomes a matmul against a one-hot segment matrix — but here the output
-is indexed by *segment id* instead of scattered back to first-occurrence
-positions, which IS the compaction (segment ids are dense in
-0..n_segments-1 because the row is sorted):
+TPU adaptation: the same one-hot segment matmul as ``segment_reduce``
+(shared: :func:`repro.kernels.segment_reduce.kernel.onehot_segment_sums`),
+but the output stays indexed by *segment id* instead of being scattered
+back to first-occurrence positions, which IS the compaction (segment ids
+are dense in 0..n_segments-1 because the row is sorted).  Four rows ride
+one MXU pass:
 
-    seg_onehot[i, s] = (seg_id[i] == s)          (C x C, built from iota)
-    agg = seg_onehot^T @ values                  (compacted segment sums)
-    ck[s] = min_i (first[i] & seg_id[i] == s ? keys[i] : PAD_KEY)
+    values          -> agg[s]   compacted segment sums
+    first * lo16(k) -> lo[s]    low 16 key bits of segment s
+    first * hi16(k) -> hi[s]    high 16 key bits of segment s
+    first           -> occ[s]   1 where segment s exists
 
-Values ride the MXU in float32; keys stay int32 throughout (a one-hot
-matmul would round-trip them through float32, which is not exact past
-2**24 — PAD_KEY alone is 2**31 - 1), so the key compaction is a masked
-min-reduce on the VPU.
+Each segment has exactly one first occurrence, so ``lo``/``hi`` are that
+key's halves — exact in float32, where a whole int32 key (PAD_KEY alone is
+2**31 - 1) would not be — and the key is reassembled in int32 afterwards.
 
-Grid: (n_tasks,); blocks: keys/values (1, C) -> out (1, C).
+Grid: (n_tasks,); blocks: seg (1, C), lhs (8, C) -> out (8, C).
 """
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-PAD_KEY = jnp.iinfo(jnp.int32).max
+from repro.kernels.segment_reduce.kernel import (
+    PAD_KEY,
+    onehot_segment_sums,
+    pad_lanes,
+    padded_width,
+    segment_ids,
+)
 
-
-def _local_reduce_kernel(k_ref, v_ref, ok_ref, ov_ref):
-    keys = k_ref[0]                      # (C,) sorted, PAD_KEY = invalid
-    vals = v_ref[0].astype(jnp.float32)
-    C = keys.shape[0]
-    valid = keys != PAD_KEY
-    pos = jax.lax.iota(jnp.int32, C)
-    prev = jnp.roll(keys, 1)
-    first = ((keys != prev) | (pos == 0)) & valid
-    seg_id = jnp.cumsum(first.astype(jnp.int32)) - 1
-    seg_id = jnp.where(valid, seg_id, C - 1)
-    # one-hot segment matrix -> MXU segment sums, compacted by segment id
-    iota = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    onehot = (seg_id[:, None] == iota).astype(jnp.float32)   # (i, s)
-    vals = jnp.where(valid, vals, 0.0)
-    agg = jax.lax.dot_general(
-        onehot, vals[:, None], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]                               # (C,) sums at slot = segment id
-    # front-packed keys: each segment's key lands at slot seg_id, in
-    # int32 (mask + min-reduce; empty slots stay PAD_KEY)
-    mask = first[:, None] & (seg_id[:, None] == iota)
-    ck = jnp.min(
-        jnp.where(mask, keys[:, None], PAD_KEY), axis=0
-    )
-    ok_ref[0] = ck
-    ov_ref[0] = jnp.where(ck != PAD_KEY, agg, 0.0).astype(ov_ref.dtype)
+_SUBLANES = 8  # lhs rows padded to one full (8, 128) tile
 
 
 def local_reduce_fwd(keys, values, *, interpret: bool = True):
-    """keys/values: (N, C) per-task spill-sorted rows.  Returns
-    (out_k, out_v) of the same shape with each row's equal-key aggregates
-    front-packed in ascending key order, (PAD_KEY, 0) tail."""
+    """keys (N, C) int32 per-task spill-sorted rows; values (N, C) float32.
+    Returns (out_k, out_v) of the same shape with each row's equal-key
+    aggregates front-packed in ascending key order, (PAD_KEY, 0) tail."""
     N, C = keys.shape
-    return pl.pallas_call(
-        _local_reduce_kernel,
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((1, C), lambda r: (r, 0)),
-            pl.BlockSpec((1, C), lambda r: (r, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, C), lambda r: (r, 0)),
-            pl.BlockSpec((1, C), lambda r: (r, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, C), keys.dtype),
-            jax.ShapeDtypeStruct((N, C), values.dtype),
-        ],
-        interpret=interpret,
-    )(keys, values)
+    Cp = padded_width(C)
+    keys = pad_lanes(keys, Cp, PAD_KEY)
+    first, seg = segment_ids(keys)
+    f = first.astype(jnp.float32)
+    bits = keys.astype(jnp.uint32)
+    lo = (bits & 0xFFFF).astype(jnp.float32)
+    hi = (bits >> 16).astype(jnp.float32)
+    vals = jnp.where(seg >= 0, pad_lanes(values, Cp, 0.0), 0.0)
+    rows = [vals, f * lo, f * hi, f]
+    zeros = jnp.zeros_like(vals)
+    lhs = jnp.stack(rows + [zeros] * (_SUBLANES - len(rows)), axis=1)
+    out = onehot_segment_sums(seg, lhs, scatter_back=False,
+                              interpret=interpret)
+    agg, lo_s, hi_s, occ = (out[:, j, :C] for j in range(4))
+    key = (hi_s.astype(jnp.uint32) << 16) | lo_s.astype(jnp.uint32)
+    live = occ > 0
+    out_k = jnp.where(live, key.astype(jnp.int32), PAD_KEY)
+    out_v = jnp.where(live, agg, 0.0)
+    return out_k, out_v

@@ -288,9 +288,7 @@ def estimate_step_time(arch: str, shape_name: str, mesh, *,
 
 
 def _raw_costs(compiled, n_devices):
-    from repro.compat import cost_analysis_dict
-
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = costmodel.parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

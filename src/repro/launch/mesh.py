@@ -8,19 +8,25 @@ import; everything else sees the real device count.
 
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (smoke tests, tuner factorization sweeps)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh (smoke tests, tuner factorization sweeps), with
+    ``Auto`` axes: the LM substrate's sharding rules are constraints for
+    the partitioner, not explicit array shardings."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def mesh_axes_names(mesh) -> tuple[str, ...]:

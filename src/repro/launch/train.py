@@ -87,9 +87,9 @@ def run_training(
     """Returns {"losses": [...], "step_seconds": [...], "last_step": int}."""
     optim_cfg = optim_cfg or adamw.AdamWConfig(lr=loop.lr)
     if mesh is None:
-        from repro.compat import make_mesh as _make_mesh
+        from repro.launch.mesh import make_mesh
 
-        mesh = _make_mesh((1, jax.device_count()), ("data", "model"))
+        mesh = make_mesh((1, jax.device_count()), ("data", "model"))
     jitted, psh, osh = _make_sharded_step(cfg, optim_cfg, step_cfg, mesh)
     pipeline = TokenPipeline(data_cfg)
 

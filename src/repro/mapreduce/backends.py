@@ -12,7 +12,8 @@ the same contract as :func:`repro.mapreduce.phases.segment_sum_sorted`):
 
 * ``jnp``    — scatter-add segment sum (the portable reference);
 * ``pallas`` — the Pallas TPU ``segment_reduce`` kernel (MXU one-hot
-  matmul formulation; interpret mode off-TPU), ``sum`` only;
+  matmul formulation; interpret mode on the CPU platform only), ``sum``
+  only, partition width at most ``kernels.segment_reduce.MAX_C``;
 * ``xla``    — ``jax.ops.segment_sum`` / ``segment_max`` primitives.
 
 Shuffle backends:
@@ -90,6 +91,22 @@ class JnpReduceBackend(ReduceBackend):
         return ok, ov
 
 
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run interpreted: only on the CPU
+    platform (tests, debugging).  A TPU compiles them; any other platform
+    raises rather than silently running the interpreter in place of the
+    kernel a run meant to measure."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise ValueError(
+        f"pallas reduce backend runs compiled on 'tpu' or interpreted on "
+        f"'cpu', not on {platform!r}"
+    )
+
+
 class PallasReduceBackend(ReduceBackend):
     """The Pallas TPU segment-reduce kernel (one grid step per partition).
 
@@ -99,44 +116,35 @@ class PallasReduceBackend(ReduceBackend):
     backends.  Workloads with per-key totals near that bound should use a
     different backend (tests/test_backends.py pins this boundary).
 
-    ``interpret=None`` (default) auto-selects: the compiled kernel on TPU,
-    interpret mode everywhere else.
+    The kernels hold a (C, C) one-hot in VMEM, so a partition (or, with
+    the combiner, a map-task row) wider than ``MAX_C`` raises a
+    ``ValueError`` naming the limit when the job is traced.
     """
 
     name = "pallas"
     supported_ops = ("sum",)
     EXACT_INT_BOUND = 2 ** 24  # float32 integer-exactness limit
 
-    def __init__(self, interpret: bool | None = None):
-        self.interpret = interpret
-
-    def reduce(self, keys, values, reduce_op: str):
+    def _check_op(self, reduce_op: str):
         if reduce_op not in self.supported_ops:
             raise ValueError(
                 f"pallas reduce backend supports {self.supported_ops}, "
                 f"got {reduce_op!r}"
             )
+
+    def reduce(self, keys, values, reduce_op: str):
+        self._check_op(reduce_op)
         from repro.kernels.segment_reduce import segment_reduce
 
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        return segment_reduce(keys, values, interpret=interpret)
+        return segment_reduce(keys, values, interpret=pallas_interpret())
 
     def combine(self, keys, values, reduce_op: str):
         # Native compacting kernel: the one-hot segment matmul indexed by
         # segment id front-packs in one pass — no host-visible sort.
-        if reduce_op not in self.supported_ops:
-            raise ValueError(
-                f"pallas reduce backend supports {self.supported_ops}, "
-                f"got {reduce_op!r}"
-            )
+        self._check_op(reduce_op)
         from repro.kernels.local_reduce import local_reduce
 
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        return local_reduce(keys, values, interpret=interpret)
+        return local_reduce(keys, values, interpret=pallas_interpret())
 
 
 class XlaReduceBackend(ReduceBackend):

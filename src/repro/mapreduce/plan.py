@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map as _shard_map
 from repro.mapreduce import backends as _backends
 from repro.mapreduce import phases
 from repro.mapreduce.phases import PAD_KEY, map_phase, reduce_local, \
@@ -891,17 +890,19 @@ class ExecutionPlan:
         #: contraction shrinks the literal all_to_all itself
         n_local_c = waves_m * (combine_cap if combiner else P)
 
+        from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P_
 
         spec2 = P_(axis, None)
         spec3 = P_(axis, None, None)
+        replicated = NamedSharding(mesh, P_())
 
         def smap(worker_fn, in_specs, out_specs):
             # pallas_call has no replication rule; every output is
             # axis-sharded anyway, so the check adds nothing here.
-            return _shard_map(
+            return jax.shard_map(
                 worker_fn, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check=False,
+                out_specs=out_specs, check_vma=False,
             )
 
         def prep(tokens):
@@ -958,6 +959,11 @@ class ExecutionPlan:
             # (W, waves_r, cap) -> (R, cap) indexed by reducer id: reducer
             # r lives on worker r % W at local slot r // W, so row r of
             # the slot-major stacking is exactly reducer r's partition.
+            # Gather the worker shards first: folding the sharded worker
+            # axis into the reducer axis has no sharding to carry on a
+            # mesh with Explicit axes (jax.make_mesh's default).
+            ok = jax.sharding.reshard(ok, replicated)
+            ov = jax.sharding.reshard(ov, replicated)
             cap = ok.shape[-1]
             ok = ok.transpose(1, 0, 2).reshape(-1, cap)[:R]
             ov = ov.transpose(1, 0, 2).reshape(-1, cap)[:R]
@@ -992,13 +998,14 @@ class ExecutionPlan:
                 # dropped: (W, 2) per-worker [send, recv] counters.
                 return ok, ov, dropped
 
-            jitted = jax.jit(whole)
-
             if not counters:
                 def plain(tokens):
-                    ok, ov, dropped = jitted(tokens)
+                    ok, ov, dropped = whole(tokens)
                     return ok, ov, dropped.sum()
-                return plain
+                # jitted like fused(): callers may lower + compile ahead
+                return jax.jit(plain)
+
+            jitted = jax.jit(whole)
 
             def with_counters(tokens):
                 ok, ov, dropped = jitted(tokens)

@@ -7,8 +7,8 @@ observability layer that makes both possible on the live engine:
     trace.py     — PhaseStats / JobTrace / PhaseRecorder: per-phase wall
                    times + resource counters with checkable conservation
                    laws; thread a recorder through ``build_job(recorder=)``
-    estimator.py — static per-phase flops/bytes via XLA cost_analysis
-                   (compat-shimmed), no execution required
+    estimator.py — static per-phase flops/bytes via XLA cost_analysis,
+                   no execution required
     models.py    — one regression per (phase, resource) on the paper's
                    basis, composed total-time prediction, ModelDatabase
                    storage under resource-qualified keys

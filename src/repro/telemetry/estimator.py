@@ -6,8 +6,7 @@ anything.  Each phase function of the canonical
 :class:`repro.mapreduce.plan.ExecutionPlan` (the same stepper loops every
 execution mode runs) is lowered and compiled for abstract (shape-only)
 inputs, and the compiled executable's cost analysis (flops, bytes
-accessed) is read through the version-compat shim
-:func:`repro.compat.compiled_cost_analysis`.
+accessed) is read by :func:`compiled_cost_analysis`.
 
 The estimates feed two consumers:
 
@@ -27,12 +26,27 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import compiled_cost_analysis
 from repro.mapreduce.phases import PAIR_BYTES
 from repro.mapreduce.plan import ExecutionPlan
 
 #: cost_analysis key for bytes moved (XLA's name, with fallbacks).
 _BYTES_KEYS = ("bytes accessed", "bytes_accessed")
+
+
+def compiled_cost_analysis(fn, *abstract_args) -> dict:
+    """Lower + compile ``fn`` for abstract (shape/dtype-only) arguments and
+    return its XLA cost analysis as a dict.
+
+    Returns ``{}`` when the backend provides no cost analysis or compiling
+    the probe fails — callers treat an empty dict as "estimates
+    unavailable", never as an error (telemetry must not take the engine
+    down).
+    """
+    try:
+        compiled = jax.jit(fn).lower(*abstract_args).compile()
+        return dict(compiled.cost_analysis() or {})
+    except Exception:  # pragma: no cover - backend dependent
+        return {}
 
 
 def _pick(cost: dict, *keys, default: float = 0.0) -> float:

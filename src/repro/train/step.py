@@ -210,16 +210,14 @@ def build_compressed_dp_train_step(cfg: ModelConfig,
         batch_spec = jax.tree.map(
             lambda l: P(axis, *([None] * (l.ndim - 1))), batch_like
         )
-        from repro.compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(rep(params_like), rep(opt_like), rep(err_like),
                       batch_spec),
             out_specs=(rep(params_like), rep(opt_like), rep(err_like),
                        {"loss": P(), "grad_norm": P(), "lr": P()}),
-            check=False,
+            check_vma=False,
         )
 
     return make

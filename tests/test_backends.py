@@ -106,6 +106,35 @@ class TestBackendValidation:
         with pytest.raises(ValueError, match="supports"):
             build_job(app, cfg, 64)
 
+    @pytest.mark.parametrize("combiner", [False, True])
+    def test_pallas_partition_wider_than_max_c_rejected(self, combiner):
+        """The kernels' (C, C) one-hot bounds C: a wider partition (or, with
+        the combiner, a wider map-task row) raises a ValueError naming the
+        limit instead of reaching the TPU compiler or another backend."""
+        from repro.kernels.segment_reduce import MAX_C
+
+        corpus = wordcount_corpus(MAX_C + 1, vocab_size=16, seed=0)
+        cfg = JobConfig(num_mappers=1, num_reducers=1, combiner=combiner,
+                        reduce_backend="pallas")
+        job = build_job(wordcount(16), cfg, len(corpus))
+        with pytest.raises(ValueError, match=f"C <= {MAX_C}"):
+            job(corpus)
+
+    @pytest.mark.parametrize("platform,interpret",
+                             [("cpu", True), ("tpu", False), ("gpu", None)])
+    def test_pallas_interprets_only_on_cpu(self, monkeypatch, platform,
+                                           interpret):
+        """Interpret mode is chosen only on the CPU platform; any platform
+        other than cpu or tpu raises instead of silently interpreting."""
+        from repro.mapreduce import backends
+
+        monkeypatch.setattr(backends.jax, "default_backend", lambda: platform)
+        if interpret is None:
+            with pytest.raises(ValueError, match=repr(platform)):
+                backends.pallas_interpret()
+        else:
+            assert backends.pallas_interpret() is interpret
+
     def test_get_reduce_backend_unknown_name(self):
         with pytest.raises(ValueError, match="registered"):
             get_reduce_backend("missing")

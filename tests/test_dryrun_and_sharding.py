@@ -127,7 +127,7 @@ def test_small_multipod_dryrun(tmp_path):
         [sys.executable, "-c", _DRYRUN_SCRIPT],
         capture_output=True, text=True, timeout=580,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": str(tmp_path)},
+             "HOME": str(tmp_path), "JAX_PLATFORMS": "cpu"},
         cwd=__file__.rsplit("/tests/", 1)[0],
     )
     assert "DRYRUN_SMALL_OK" in proc.stdout, proc.stderr[-3000:]

@@ -27,9 +27,15 @@ from repro.telemetry import PhaseRecorder
 mesh = jax.make_mesh((4,), ("workers",))
 corpus = wordcount_corpus(5000, vocab_size=129, seed=11)
 app = wordcount(129)
-for (M, R), backend in [((8, 6), "jnp"), ((5, 9), "pallas"), ((4, 4), "xla")]:
+# pallas runs with the combiner: it keeps every kernel row (map-task rows
+# of 1000 pairs, reduce partitions of 1032 slots) within the kernels'
+# MAX_C, which the uncombined 8000-slot partitions here would exceed
+for (M, R), backend, combiner in [((8, 6), "jnp", False),
+                                  ((5, 9), "pallas", True),
+                                  ((4, 4), "xla", False)]:
     cfg = JobConfig(num_mappers=M, num_reducers=R, num_workers=4,
-                    capacity_factor=12.0, reduce_backend=backend)
+                    capacity_factor=12.0, reduce_backend=backend,
+                    combiner=combiner)
     plan = ExecutionPlan(app, cfg, len(corpus))
     ok, ov, dropped = plan.sharded(mesh)(corpus)
     assert int(dropped) == 0, (M, R)
@@ -90,7 +96,7 @@ def test_sharded_engine_matches_global(tmp_path):
         [sys.executable, "-c", _SCRIPT],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": str(tmp_path)},
+             "HOME": str(tmp_path), "JAX_PLATFORMS": "cpu"},
         cwd=__file__.rsplit("/tests/", 1)[0],
     )
     assert "SHARDED_OK" in proc.stdout, proc.stderr[-3000:]
