@@ -16,7 +16,12 @@ combiner, partition, and reduce logic — one in ``build_job`` and one in
 * :func:`bucket_scatter`    — capacity-bounded partition scatter, with
   overflow *accounting* (the ``dropped`` count) instead of silent loss;
 * :func:`reduce_phase` / :func:`reduce_local` — wave-scheduled reduce
-  through a pluggable :class:`repro.mapreduce.backends.ReduceBackend`.
+  through a pluggable :class:`repro.mapreduce.backends.ReduceBackend`;
+* :func:`scoped`            — a phase function under its named scope
+  ``mr.<phase>``, so a profile of any program built from it can say which
+  phase each device op belongs to;
+* :func:`pair_counts` / :func:`shuffle_counts` / :func:`partition_counts` /
+  :func:`output_counts` — the device counters at each phase boundary.
 
 Everything is pure ``jnp`` with static shapes, so every phase composes
 under ``jit``, ``vmap``, ``scan``, and ``shard_map``.
@@ -24,6 +29,7 @@ under ``jit``, ``vmap``, ``scan``, and ``shard_map``.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -44,10 +50,68 @@ PAIR_BYTES = 8
 COMBINABLE_OPS = ("sum", "max")
 
 
+#: prefix of the named scope every phase runs under (``mr.map``,
+#: ``mr.combine``, ``mr.shuffle``, ``mr.reduce``): the ``op_name`` metadata
+#: of each HLO instruction a phase emits holds ``mr.<phase>``
+SCOPE = "mr"
+
+
 def count_live(keys) -> jnp.ndarray:
     """Number of live (non-PAD) slots in a key array — the counter primitive
     shared by the telemetry layer and the conservation tests."""
     return (jnp.asarray(keys) != PAD_KEY).sum()
+
+
+def scoped(phase: str, fn):
+    """``fn`` traced under ``jax.named_scope("mr.<phase>")``.  A scope is
+    metadata only: the program runs the same operations without it."""
+    name = f"{SCOPE}.{phase}"
+
+    @functools.wraps(fn)
+    def run(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return run
+
+
+# Device counters, one function per phase boundary.  Each returns int32
+# device scalars keyed ``<phase>.<counter>``, so a job reduces them on the
+# device and only scalars reach the host.
+
+
+def _total(x) -> jnp.ndarray:
+    return jnp.sum(x, dtype=jnp.int32)
+
+
+def pair_counts(phase: str, pvalid) -> dict:
+    """Out of the map or the combine: ``<phase>.pairs``, the live pairs
+    the phase emits."""
+    return {f"{phase}.pairs": _total(pvalid)}
+
+
+def shuffle_counts(keys, pvalid, dropped) -> dict:
+    """Through the shuffle: the pair slots it sorts (live or not), the
+    live pairs among them, and the pairs it dropped."""
+    return {
+        "shuffle.slots": jnp.int32(keys.size),
+        "shuffle.pairs": _total(pvalid),
+        "shuffle.dropped": _total(dropped),
+    }
+
+
+def partition_counts(part_keys) -> dict:
+    """Into the reduce: the partition slots it walks (R x cap) and the live
+    (non-PAD) pairs in them."""
+    return {
+        "reduce.slots": jnp.int32(part_keys.size),
+        "reduce.pairs": _total(part_keys != PAD_KEY),
+    }
+
+
+def output_counts(out_keys) -> dict:
+    """Out of the reduce: the live output segments, one per key."""
+    return {"reduce.segments": _total(out_keys != PAD_KEY)}
 
 
 def task_setup(dim: int, rounds: int, seed_val):

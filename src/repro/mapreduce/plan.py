@@ -45,6 +45,13 @@ The canonical stepper contract (all shapes static per plan):
   replaced by the block transpose it implements);
 * ``reduce_step(W)(pk, pv, ok, ov, start)`` → updated ``(R, cap)`` outputs.
 
+Every phase function runs under the named scope ``mr.<phase>``
+(:func:`repro.mapreduce.phases.scoped`), in every mode, so a profile of
+any of these programs can attribute each device op to its phase; and
+every mode can count, on the device, the pairs and slots at each phase
+boundary (``fused(counters=True)``, ``pipelined(counters=True)``, and the
+counters ``traced`` and ``sharded(recorder=)`` record).
+
 A map task's output depends only on its split and the frozen config —
 never on W or on which wave (or mode) ran it — and all buffers are
 task-major with exactly M (or R) live rows, so bit-exactness across
@@ -55,6 +62,7 @@ suite in ``tests/test_plan.py`` instead of once per hand-rolled path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os as _os
 import time as _time
@@ -62,6 +70,7 @@ import time as _time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.mapreduce import backends as _backends
 from repro.mapreduce import phases
@@ -83,6 +92,74 @@ def _pad_rows(arr, n_extra: int, fill):
         return arr
     pad = jnp.full((n_extra,) + arr.shape[1:], fill, dtype=arr.dtype)
     return jnp.concatenate([arr, pad], axis=0)
+
+
+#: the device counters of each phase, from its program's arguments and
+#: outputs (the functions of :mod:`repro.mapreduce.phases`); the shuffle's
+#: also counts the partitions it hands the reduce
+_PHASE_COUNTS = {
+    "map": lambda args, out: phases.pair_counts("map", out[2]),
+    "combine": lambda args, out: phases.pair_counts("combine", out[2]),
+    "shuffle": lambda args, out: {
+        **phases.shuffle_counts(args[0], args[2], out[2]),
+        **phases.partition_counts(out[0]),
+    },
+    "reduce": lambda args, out: phases.output_counts(out[0]),
+}
+
+
+def _compose(fns: dict, counters: bool = False):
+    """The job of a phase dict (:meth:`ExecutionPlan.phase_fns` and kin):
+    map, the combine if present, shuffle, reduce.  ``job(*inputs) -> (ok,
+    ov, dropped)``; with ``counters`` also a dict of the phases' int32
+    device counters, computed in the same program."""
+
+    def job(*inputs):
+        stats = {}
+
+        def run(phase, *args):
+            out = fns[phase](*args)
+            if counters:
+                stats.update(_PHASE_COUNTS[phase](args, out))
+            return out
+
+        bufs = run("map", *inputs)
+        if "combine" in fns:
+            bufs = run("combine", *bufs)
+        pk, pv, dropped = run("shuffle", *bufs)
+        ok, ov = run("reduce", pk, pv)
+        if counters:
+            return ok, ov, dropped, stats
+        return ok, ov, dropped
+
+    return job
+
+
+def _counted(phase: str, fn):
+    """``fn`` jitted as one fenced phase program that also returns the
+    phase's device counters: ``prog(*args) -> (out, counts)``."""
+
+    @functools.wraps(fn)
+    def prog(*args):
+        out = fn(*args)
+        return out, _PHASE_COUNTS[phase](args, out)
+
+    return jax.jit(prog)
+
+
+def _fenced(phase: str, prog, counts: dict, *args):
+    """Run one :func:`_counted` program to completion inside the host span
+    ``mr.<phase>`` (on the profiler's clock, beside the device ops of the
+    same scope).  Adds its counters to ``counts`` as ints and returns
+    ``(out, wall_s, cpu_s)``."""
+    with TraceAnnotation(f"{phases.SCOPE}.{phase}"):
+        t0 = _time.perf_counter()
+        c0 = _time.process_time()
+        out, c = jax.block_until_ready(prog(*args))
+        cpu = _time.process_time() - c0
+        dt = _time.perf_counter() - t0
+    counts.update((k, int(v)) for k, v in jax.device_get(c).items())
+    return out, dt, cpu
 
 
 class ExecutionPlan:
@@ -530,7 +607,7 @@ class ExecutionPlan:
             fns["combine"] = self._combine_step_fn()
         fns["shuffle"] = phase_shuffle
         fns["reduce"] = phase_reduce
-        return fns
+        return {p: phases.scoped(p, f) for p, f in fns.items()}
 
     # ----------------------------------------- jitted steppers (per grant)
 
@@ -594,11 +671,12 @@ class ExecutionPlan:
     # ------------------------------------------------- phase compositions
 
     def phase_fns(self, workers: int | None = None) -> dict:
-        """The pipeline as three pure phase functions — each a stepper
-        loop (``fori_loop`` over waves) at one grant.  Shared by the
-        fused mode (composed under one jit), the traced mode (jitted and
-        fenced per phase), and the XLA cost estimator (lowered per phase
-        for abstract inputs).
+        """The pipeline as pure phase functions (map, the combine when
+        ``cfg.combiner``, shuffle, reduce) — each a stepper loop
+        (``fori_loop`` over waves) at one grant, under its named scope
+        ``mr.<phase>``.  Shared by the fused mode (composed under one
+        jit), the traced mode (jitted and fenced per phase), and the XLA
+        cost estimator (lowered per phase for abstract inputs).
         """
         W = self.cfg.num_workers if workers is None else int(workers)
         prep = self._prep_fn()
@@ -637,30 +715,26 @@ class ExecutionPlan:
             fns["combine"] = self._combine_step_fn()
         fns["shuffle"] = phase_shuffle
         fns["reduce"] = phase_reduce
-        return fns
+        return {p: phases.scoped(p, f) for p, f in fns.items()}
 
     # ---------------------------------------------------------------- modes
 
-    def fused(self, workers: int | None = None):
+    def fused(self, workers: int | None = None, counters: bool = False):
         """Mode ``fused``: the whole pipeline under one ``jit`` — the
         zero-overhead hot path.  Returns ``job(tokens) -> (out_keys
         (R, cap), out_vals (R, cap), dropped ())``.  Works for both
         shuffle families (the collective one runs its emulated
-        single-controller form; use :meth:`sharded` for a real mesh)."""
-        fns = self.phase_fns(workers)
+        single-controller form; use :meth:`sharded` for a real mesh).
 
-        def job(tokens):
-            bufs = fns["map"](tokens)
-            if "combine" in fns:
-                bufs = fns["combine"](*bufs)
-            pk, pv, dropped = fns["shuffle"](*bufs)
-            ok, ov = fns["reduce"](pk, pv)
-            return ok, ov, dropped
-
-        return jax.jit(job)
+        With ``counters=True`` the job also returns ``stats``, the int32
+        device scalars ``map.pairs``, ``combine.pairs`` (with the
+        combiner), ``shuffle.slots`` / ``.pairs`` / ``.dropped`` and
+        ``reduce.slots`` / ``.pairs`` / ``.segments``, counted in the same
+        program; the outputs are the same."""
+        return jax.jit(_compose(self.phase_fns(workers), counters))
 
     def pipelined(self, workers: int | None = None,
-                  depth: int | None = None):
+                  depth: int | None = None, counters: bool = False):
         """Mode ``pipelined``: the fused pipeline with map and reduce
         waves software-pipelined at overlap depth D (default
         ``cfg.overlap_depth``) — wave group g's compute overlaps group
@@ -669,28 +743,19 @@ class ExecutionPlan:
         plus commit/compute overlap is where the wall-clock win comes
         from on wave-count-dominated (shuffle-heavy) configs.  Outputs
         are bit-exact vs :meth:`fused` by construction; jitted jobs are
-        cached per ``(W, depth)`` grant."""
+        cached per ``(W, depth)`` grant.  ``counters=True`` adds the
+        device counters, as in :meth:`fused`."""
         W = self.cfg.num_workers if workers is None else int(workers)
         D = (getattr(self.cfg, "overlap_depth", 1)
              if depth is None else int(depth))
         if D < 1:
             raise ValueError(f"overlap depth must be >= 1, got {D}")
-        key = (W, D, self.combiner)
+        key = (W, D, self.combiner, bool(counters))
         if key in self._jit_pipelined:
             self._cache_hits += 1
             return self._jit_pipelined[key]
         self._cache_misses += 1
-        fns = self.pipelined_phase_fns(W, D)
-
-        def job(tokens):
-            bufs = fns["map"](tokens)
-            if "combine" in fns:
-                bufs = fns["combine"](*bufs)
-            pk, pv, dropped = fns["shuffle"](*bufs)
-            ok, ov = fns["reduce"](pk, pv)
-            return ok, ov, dropped
-
-        jitted = jax.jit(job)
+        jitted = jax.jit(_compose(self.pipelined_phase_fns(W, D), counters))
         self._jit_pipelined[key] = jitted
         return jitted
 
@@ -698,26 +763,23 @@ class ExecutionPlan:
                depth: int | None = None):
         """Mode ``traced``: phase-fenced stepper loops feeding a
         :class:`repro.telemetry.PhaseRecorder`.  Same semantics and
-        outputs as :meth:`fused`; counters are measured from the actual
-        phase outputs (host-side numpy reductions), so conservation laws
-        are checkable invariants rather than config-derived tautologies.
+        outputs as :meth:`fused`.  Each phase is its own program, which
+        also reduces the phase's counters on the device (only scalars
+        reach the host), so conservation laws are checkable invariants
+        rather than config-derived tautologies; each runs inside the host
+        span ``mr.<phase>``, on the profiler's clock.
 
         With overlap depth D > 1 (``depth=`` or ``cfg.overlap_depth``)
         the map/reduce phases compile in their pipelined form and the
         trace gains a fourth ``"pipeline"`` phase carrying the
-        cross-phase residual wall time (total minus the three fenced
-        phases) plus ``overlap_depth`` / ``overlap_s`` counters — so the
-        timing conservation law still closes over the phase list.
+        cross-phase residual wall time (total minus the fenced phases)
+        and the ``overlap_depth`` counter — so the timing conservation
+        law still closes over the phase list.
         """
         D = (getattr(self.cfg, "overlap_depth", 1)
              if depth is None else int(depth))
-        fns = self.pipelined_phase_fns(workers, D)
-        jit_map = jax.jit(fns["map"])
-        jit_combine = (
-            jax.jit(fns["combine"]) if "combine" in fns else None
-        )
-        jit_shuffle = jax.jit(fns["shuffle"])
-        jit_reduce = jax.jit(fns["reduce"])
+        progs = {p: _counted(p, f)
+                 for p, f in self.pipelined_phase_fns(workers, D).items()}
         m = self.meta(workers)
         pair_bytes = phases.PAIR_BYTES
         app, cfg = self.app, self.cfg
@@ -735,36 +797,26 @@ class ExecutionPlan:
 
         def _run(tokens, trace):
             t_job = _time.perf_counter()
+            c = {}
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            bk, bv, bp = jax.block_until_ready(jit_map(tokens))
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
-            pairs_emitted = int(np.asarray(bp).sum())
+            bufs, dt, cpu = _fenced("map", progs["map"], c, tokens)
             trace.record_phase(
                 "map", dt,
                 tasks=m["mappers"], waves=m["map_waves"],
                 records_in=m["input_len"],
-                pairs_emitted=pairs_emitted, pairs_capacity=m["n_pairs"],
+                pairs_emitted=c["map.pairs"], pairs_capacity=m["n_pairs"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
-            if jit_combine is not None:
-                t0 = _time.perf_counter()
-                c0 = _time.process_time()
-                bk, bv, bp = jax.block_until_ready(
-                    jit_combine(bk, bv, bp)
-                )
-                cpu = _time.process_time() - c0
-                dt = _time.perf_counter() - t0
-                pairs_combined = int(np.asarray(bp).sum())
+            if "combine" in progs:
+                bufs, dt, cpu = _fenced("combine", progs["combine"], c,
+                                        *bufs)
                 trace.record_phase(
                     "combine", dt,
                     tasks=m["mappers"],
-                    pairs_in=pairs_emitted, pairs_out=pairs_combined,
-                    bytes_in=pairs_emitted * pair_bytes,
-                    bytes_out=pairs_combined * pair_bytes,
+                    pairs_in=c["map.pairs"], pairs_out=c["combine.pairs"],
+                    bytes_in=c["map.pairs"] * pair_bytes,
+                    bytes_out=c["combine.pairs"] * pair_bytes,
                     combine_capacity=m["combine_capacity"],
                     cpu_s=cpu, cpu_workers=_NCPU,
                     # Combining is map-local CPU work: it moves no fabric
@@ -772,26 +824,18 @@ class ExecutionPlan:
                     # contraction shows up in the *shuffle* counters.
                     net_bytes=0.0,
                 )
-                shuffle_pairs_in = pairs_combined
-            else:
-                shuffle_pairs_in = pairs_emitted
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            pk, pv, dropped = jax.block_until_ready(
-                jit_shuffle(bk, bv, bp)
+            (pk, pv, dropped), dt, cpu = _fenced(
+                "shuffle", progs["shuffle"], c, *bufs
             )
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
-            n_dropped = int(dropped)
-            pairs_out = int((np.asarray(pk) != int(PAD_KEY)).sum())
+            pairs_in = c["shuffle.pairs"]
             trace.record_phase(
                 "shuffle", dt,
-                pairs_in=shuffle_pairs_in, pairs_out=pairs_out,
-                pairs_dropped=n_dropped,
-                bytes_in=shuffle_pairs_in * pair_bytes,
-                bytes_out=pairs_out * pair_bytes,
-                bytes_dropped=n_dropped * pair_bytes,
+                pairs_in=pairs_in, pairs_out=c["reduce.pairs"],
+                pairs_dropped=c["shuffle.dropped"],
+                bytes_in=pairs_in * pair_bytes,
+                bytes_out=c["reduce.pairs"] * pair_bytes,
+                bytes_dropped=c["shuffle.dropped"] * pair_bytes,
                 partitions=m["reducers"],
                 partition_capacity=int(pk.shape[1]),
                 cpu_s=cpu, cpu_workers=_NCPU,
@@ -799,21 +843,17 @@ class ExecutionPlan:
                 # crosses the wire (dropped ones included) — post-combine
                 # pairs when the combiner is on, which is exactly the
                 # byte contraction the fabric sees.
-                net_bytes=shuffle_pairs_in * pair_bytes,
+                net_bytes=pairs_in * pair_bytes,
                 net_s=dt,
             )
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            ok, ov = jax.block_until_ready(jit_reduce(pk, pv))
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
-            segments = int((np.asarray(ok) != int(PAD_KEY)).sum())
+            (ok, ov), dt, cpu = _fenced("reduce", progs["reduce"], c,
+                                        pk, pv)
             trace.record_phase(
                 "reduce", dt,
                 tasks=m["reducers"], waves=m["reduce_waves"],
-                segments_out=segments,
-                segment_slots=m["reducers"] * int(pk.shape[1]),
+                segments_out=c["reduce.segments"],
+                segment_slots=c["reduce.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
@@ -828,8 +868,7 @@ class ExecutionPlan:
                 residual = max(0.0, total - trace.phase_time_sum())
                 trace.record_phase(
                     "pipeline", residual,
-                    overlap_depth=D, overlap_s=0.0,
-                    net_bytes=0.0,
+                    overlap_depth=D, net_bytes=0.0,
                 )
             trace.finish(total)
             return ok, ov, dropped
@@ -864,7 +903,11 @@ class ExecutionPlan:
         With ``counters=True`` the returned job additionally yields a
         ``stats`` dict reducing the per-worker overflow counters across
         shards (``dropped_send`` / ``dropped_recv`` /
-        ``dropped_per_worker``).
+        ``dropped_per_worker``) and, without a recorder, the device
+        counters of :meth:`fused` summed over the workers.  The worker
+        phases run under the same ``mr.<phase>`` scopes as every mode's
+        (the input split with the map); the output gather after the
+        reduce has none of its own.
         """
         cfg, app = self.cfg, self.app
         W = mesh.shape[axis]
@@ -955,6 +998,14 @@ class ExecutionPlan:
             ok, ov = reduce_local(app, cfg, bk[0], bv[0], reduce_backend)
             return ok[None], ov[None]
 
+        prep = phases.scoped("map", prep)
+        wfns = {"map": w_map}
+        if combiner:
+            wfns["combine"] = w_combine
+        wfns["shuffle"] = w_shuffle
+        wfns["reduce"] = w_reduce
+        wfns = {p: phases.scoped(p, f) for p, f in wfns.items()}
+
         def to_reducer_major(ok, ov):
             # (W, waves_r, cap) -> (R, cap) indexed by reducer id: reducer
             # r lives on worker r % W at local slot r // W, so row r of
@@ -979,61 +1030,59 @@ class ExecutionPlan:
         if recorder is None:
             # Fused single mesh program (the zero-overhead deployment
             # path): all phases in one shard_map body.
+            worker_job = _compose(wfns, counters)
+
             def worker(splits, valid):
-                k, v, pv = w_map(splits, valid)
-                if combiner:
-                    k, v, pv = w_combine(k, v, pv)
-                bk, bv, dropped = w_shuffle(k, v, pv)
-                ok, ov = w_reduce(bk, bv)
-                return ok, ov, dropped
+                if not counters:
+                    return worker_job(splits, valid)
+                ok, ov, dropped, stats = worker_job(splits, valid)
+                return ok, ov, dropped, {k: v[None]
+                                         for k, v in stats.items()}
 
-            shard_fn = smap(
-                worker, (spec3, spec3), (spec3, spec3, spec2)
-            )
-
-            def whole(tokens):
-                splits, vsplit = prep(tokens)
-                ok, ov, dropped = shard_fn(splits, vsplit)
-                ok, ov = to_reducer_major(ok, ov)
-                # dropped: (W, 2) per-worker [send, recv] counters.
-                return ok, ov, dropped
+            out_specs = (spec3, spec3, spec2)
+            if counters:
+                out_specs += (P_(axis),)
+            shard_fn = smap(worker, (spec3, spec3), out_specs)
 
             if not counters:
                 def plain(tokens):
-                    ok, ov, dropped = whole(tokens)
+                    ok, ov, dropped = shard_fn(*prep(tokens))
+                    ok, ov = to_reducer_major(ok, ov)
                     return ok, ov, dropped.sum()
                 # jitted like fused(): callers may lower + compile ahead
                 return jax.jit(plain)
 
+            def whole(tokens):
+                ok, ov, dropped, stats = shard_fn(*prep(tokens))
+                ok, ov = to_reducer_major(ok, ov)
+                # dropped: (W, 2) per-worker [send, recv] counters;
+                # the device counters summed over the workers.
+                return ok, ov, dropped, {k: v.sum()
+                                         for k, v in stats.items()}
+
             jitted = jax.jit(whole)
 
             def with_counters(tokens):
-                ok, ov, dropped = jitted(tokens)
+                ok, ov, dropped, device_stats = jitted(tokens)
                 per_worker = np.asarray(dropped)
-                return ok, ov, dropped.sum(), stats_from(per_worker)
+                return ok, ov, dropped.sum(), {**stats_from(per_worker),
+                                               **device_stats}
 
             return with_counters
 
         # Phase-fenced sharded execution: separate mesh programs, each
-        # wall-clocked, counters cross-shard reduced on the host.
+        # wall-clocked, its counters reduced across shards on the device.
         pair_bytes = phases.PAIR_BYTES
-        jit_map = jax.jit(
-            lambda tokens: smap(w_map, (spec3, spec3),
-                                (spec2, spec2, spec2))(*prep(tokens))
-        )
-        jit_combine = (
-            jax.jit(
-                smap(w_combine, (spec2, spec2, spec2),
-                     (spec2, spec2, spec2))
-            )
-            if combiner else None
-        )
-        jit_shuffle = jax.jit(
-            smap(w_shuffle, (spec2, spec2, spec2), (spec3, spec3, spec2))
-        )
-        jit_reduce = jax.jit(
-            smap(w_reduce, (spec3, spec3), (spec3, spec3))
-        )
+        specs = {
+            "map": ((spec3, spec3), (spec2, spec2, spec2)),
+            "combine": ((spec2, spec2, spec2), (spec2, spec2, spec2)),
+            "shuffle": ((spec2, spec2, spec2), (spec3, spec3, spec2)),
+            "reduce": ((spec3, spec3), (spec3, spec3)),
+        }
+        mesh_fns = {p: smap(f, *specs[p]) for p, f in wfns.items()}
+        map_fn = mesh_fns["map"]
+        mesh_fns["map"] = lambda tokens: map_fn(*prep(tokens))
+        progs = {p: _counted(p, f) for p, f in mesh_fns.items()}
 
         def traced_job(tokens):
             trace = recorder.start_job(app.name, cfg, input_len)
@@ -1046,59 +1095,43 @@ class ExecutionPlan:
 
         def _run(tokens, trace):
             t_job = _time.perf_counter()
+            c = {}
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            k, v, pv = jax.block_until_ready(jit_map(tokens))
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
-            pairs_emitted = int(np.asarray(pv).sum())
+            bufs, dt, cpu = _fenced("map", progs["map"], c, tokens)
             trace.record_phase(
                 "map", dt,
                 tasks=M, waves=waves_m, workers=W,
                 records_in=input_len,
-                pairs_emitted=pairs_emitted, pairs_capacity=W * n_local,
+                pairs_emitted=c["map.pairs"], pairs_capacity=W * n_local,
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
-            if jit_combine is not None:
-                t0 = _time.perf_counter()
-                c0 = _time.process_time()
-                k, v, pv = jax.block_until_ready(jit_combine(k, v, pv))
-                cpu = _time.process_time() - c0
-                dt = _time.perf_counter() - t0
-                pairs_combined = int(np.asarray(pv).sum())
+            if "combine" in progs:
+                bufs, dt, cpu = _fenced("combine", progs["combine"], c,
+                                        *bufs)
                 trace.record_phase(
                     "combine", dt,
                     tasks=M, workers=W,
-                    pairs_in=pairs_emitted, pairs_out=pairs_combined,
-                    bytes_in=pairs_emitted * pair_bytes,
-                    bytes_out=pairs_combined * pair_bytes,
+                    pairs_in=c["map.pairs"], pairs_out=c["combine.pairs"],
+                    bytes_in=c["map.pairs"] * pair_bytes,
+                    bytes_out=c["combine.pairs"] * pair_bytes,
                     combine_capacity=combine_cap,
                     cpu_s=cpu, cpu_workers=_NCPU,
                     net_bytes=0.0,
                 )
-                shuffle_pairs_in = pairs_combined
-            else:
-                shuffle_pairs_in = pairs_emitted
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            bk, bv, dropped = jax.block_until_ready(
-                jit_shuffle(k, v, pv)
+            (bk, bv, dropped), dt, cpu = _fenced(
+                "shuffle", progs["shuffle"], c, *bufs
             )
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
             per_worker = np.asarray(dropped)
-            n_dropped = int(per_worker.sum())
-            pairs_out = int((np.asarray(bk) != int(PAD_KEY)).sum())
+            pairs_in = c["shuffle.pairs"]
             trace.record_phase(
                 "shuffle", dt,
-                pairs_in=shuffle_pairs_in, pairs_out=pairs_out,
-                pairs_dropped=n_dropped,
-                bytes_in=shuffle_pairs_in * pair_bytes,
-                bytes_out=pairs_out * pair_bytes,
-                bytes_dropped=n_dropped * pair_bytes,
+                pairs_in=pairs_in, pairs_out=c["reduce.pairs"],
+                pairs_dropped=c["shuffle.dropped"],
+                bytes_in=pairs_in * pair_bytes,
+                bytes_out=c["reduce.pairs"] * pair_bytes,
+                bytes_dropped=c["shuffle.dropped"] * pair_bytes,
                 partitions=R, workers=W,
                 # The capacity the executed exchange actually allocated
                 # (the configured shuffle may have been substituted by
@@ -1107,22 +1140,18 @@ class ExecutionPlan:
                 dropped_send=int(per_worker[:, 0].sum()),
                 dropped_recv=int(per_worker[:, 1].sum()),
                 cpu_s=cpu, cpu_workers=_NCPU,
-                net_bytes=shuffle_pairs_in * pair_bytes,
+                net_bytes=pairs_in * pair_bytes,
                 net_s=dt,
             )
 
-            t0 = _time.perf_counter()
-            c0 = _time.process_time()
-            ok, ov = jax.block_until_ready(jit_reduce(bk, bv))
-            cpu = _time.process_time() - c0
-            dt = _time.perf_counter() - t0
+            (ok, ov), dt, cpu = _fenced("reduce", progs["reduce"], c,
+                                        bk, bv)
             ok, ov = to_reducer_major(ok, ov)
-            segments = int((np.asarray(ok) != int(PAD_KEY)).sum())
             trace.record_phase(
                 "reduce", dt,
                 tasks=R, waves=waves_r, workers=W,
-                segments_out=segments,
-                segment_slots=W * waves_r * int(bk.shape[-1]),
+                segments_out=c["reduce.segments"],
+                segment_slots=c["reduce.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
