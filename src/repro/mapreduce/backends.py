@@ -10,11 +10,13 @@ paper's per-application model-database pattern, reused per-backend).
 Reduce backends (per-partition sorted segment aggregation, all implementing
 the same contract as :func:`repro.mapreduce.phases.segment_sum_sorted`):
 
-* ``jnp``    — scatter-add segment sum (the portable reference);
+* ``jnp``    — a reverse segmented scan over each sorted row (the
+  portable reference; no gather, scatter or sort);
 * ``pallas`` — the Pallas TPU ``segment_reduce`` kernel (MXU one-hot
   matmul formulation; interpret mode on the CPU platform only), ``sum``
   only, partition width at most ``kernels.segment_reduce.MAX_C``;
-* ``xla``    — ``jax.ops.segment_sum`` / ``segment_max`` primitives.
+* ``xla``    — the same scan as ``jnp`` under the name that existing job
+  configurations select.
 
 Shuffle backends:
 
@@ -56,9 +58,10 @@ class ReduceBackend:
     aggregates come back *front-packed* in ascending key order with a
     (PAD_KEY, 0) tail — so the caller can truncate the row to its
     distinct-key bound and shrink the shuffle stream.  The default
-    derivation sorts the sparse ``reduce`` output (first occurrences of a
-    sorted row are ascending and distinct, so an ascending key sort IS the
-    compaction); backends with a native compacting kernel override it.
+    derivation sorts the sparse ``reduce`` output with its values
+    (first occurrences of a sorted row are ascending and distinct, so an
+    ascending key sort IS the compaction); backends with a native
+    compacting kernel override it.
     """
 
     name: str = "abstract"
@@ -69,25 +72,23 @@ class ReduceBackend:
 
     def combine(self, keys, values, reduce_op: str):
         ok, ov = self.reduce(keys, values, reduce_op)
-        order = jnp.argsort(ok, axis=1)  # PAD_KEY sorts last
-        return (
-            jnp.take_along_axis(ok, order, axis=1),
-            jnp.take_along_axis(ov, order, axis=1),
-        )
+        # One sort carrying the values: a row's first-occurrence keys are
+        # distinct and every other slot is (PAD_KEY, 0), which sorts last,
+        # so the order of equal keys cannot matter.
+        return tuple(jax.lax.sort((ok, ov), dimension=1, num_keys=1))
 
 
 class JnpReduceBackend(ReduceBackend):
-    """Portable reference: scatter-add/max segment reduce (pure jnp)."""
+    """Portable reference: a reverse segmented scan per row (pure jnp,
+    :func:`repro.mapreduce.phases.segment_sum_sorted`)."""
 
     name = "jnp"
     supported_ops = ("sum", "max", "first")
 
     def reduce(self, keys, values, reduce_op: str):
-        ok, ov, _ = jax.vmap(
-            lambda k, v: phases.segment_sum_sorted(
-                k, v, k != PAD_KEY, reduce_op
-            )
-        )(keys, values)
+        ok, ov, _ = phases.segment_sum_sorted(
+            keys, values, keys != PAD_KEY, reduce_op
+        )
         return ok, ov
 
 
@@ -147,45 +148,11 @@ class PallasReduceBackend(ReduceBackend):
         return local_reduce(keys, values, interpret=pallas_interpret())
 
 
-class XlaReduceBackend(ReduceBackend):
-    """XLA segment primitives (``jax.ops.segment_sum`` / ``segment_max``)."""
+class XlaReduceBackend(JnpReduceBackend):
+    """The ``jnp`` backend's segmented scan under a second registered name,
+    which existing job configurations and traffic files select."""
 
     name = "xla"
-    supported_ops = ("sum", "max", "first")
-
-    def reduce(self, keys, values, reduce_op: str):
-        def one_row(k, v):
-            n = k.shape[0]
-            valid = k != PAD_KEY
-            first = jnp.concatenate(
-                [jnp.array([True]), k[1:] != k[:-1]]
-            ) & valid
-            seg = jnp.cumsum(first.astype(jnp.int32)) - 1
-            seg = jnp.where(valid, seg, n - 1)
-            if reduce_op == "sum":
-                agg = jax.ops.segment_sum(
-                    jnp.where(valid, v, 0), seg, num_segments=n
-                )
-            elif reduce_op == "max":
-                agg = jax.ops.segment_max(
-                    jnp.where(valid, v, jnp.iinfo(jnp.int32).min),
-                    seg,
-                    num_segments=n,
-                )
-            elif reduce_op == "first":
-                # Delivery order is the stable sort order, so the first
-                # value of each run already sits at the first-occurrence
-                # slot (order-dependent: deliberately not combinable).
-                agg = jax.ops.segment_sum(
-                    jnp.where(first, v, 0), seg, num_segments=n
-                )
-            else:
-                raise ValueError(reduce_op)
-            out_k = jnp.where(first, k, PAD_KEY)
-            out_v = jnp.where(first, agg[seg], 0).astype(v.dtype)
-            return out_k, out_v
-
-        return jax.vmap(one_row)(keys, values)
 
 
 # ---------------------------------------------------------------------------

@@ -140,42 +140,103 @@ def hash_to_reducer(keys, num_reducers: int):
     return (h % jnp.uint32(num_reducers)).astype(jnp.int32)
 
 
+#: width of the blocks the segmented scan runs within before it carries
+#: across them: one TPU vector register's lanes
+SCAN_BLOCK = 128
+
+
+def _shifted(x, s: int, fill):
+    """``x[..., i + s]`` along the last axis, ``fill`` past its end."""
+    pad = [(0, 0, 0)] * (x.ndim - 1) + [(0, s, 0)]
+    return jax.lax.pad(x[..., s:], jnp.asarray(fill, x.dtype), pad)
+
+
+def _doubling_scan(op, identity, ended, acc):
+    """Hillis-Steele doubling along the last axis: after the step with
+    shift s, ``acc[i]`` holds the ``op``-total over ``[i, i + 2s)`` cut
+    after the first flagged slot, and ``ended[i]`` whether a flag lies in
+    that span.  One elementwise pass over a shifted copy per step."""
+    s = 1
+    while s < acc.shape[-1]:
+        acc = jnp.where(ended, acc, op(acc, _shifted(acc, s, identity)))
+        ended = ended | _shifted(ended, s, False)
+        s *= 2
+    return ended, acc
+
+
+def _reverse_segmented_scan(op, identity, run_end, values):
+    """Along the last axis: each slot's ``op``-total from itself up to and
+    including the next slot flagged in ``run_end``.
+
+    One row at a time (``lax.map`` over the leading axes), so a row is
+    viewed as blocks of ``SCAN_BLOCK`` slots without moving it in memory;
+    the tail is padded with ``identity`` and no flag.  A doubling scan runs
+    within every block, a second one over what flows out of each block's
+    first slot, and each slot with no flag between it and its block's end
+    takes the carry from the blocks to its right.  Nothing is gathered,
+    scattered or sorted, and the number of passes is fixed by the shape.
+    """
+    if values.ndim > 1:
+        def rows(x):
+            return x.reshape(-1, x.shape[-1])
+
+        return jax.lax.map(
+            lambda row: _reverse_segmented_scan(op, identity, *row),
+            (rows(run_end), rows(values)),
+        ).reshape(values.shape)
+    n = values.shape[0]
+    blocks = -(-n // SCAN_BLOCK)
+
+    def as_blocks(x, fill):
+        tail = [(0, blocks * SCAN_BLOCK - n, 0)]
+        return jax.lax.pad(x, jnp.asarray(fill, x.dtype), tail).reshape(
+            blocks, SCAN_BLOCK
+        )
+
+    ended, acc = _doubling_scan(
+        op, identity, as_blocks(run_end, False), as_blocks(values, identity)
+    )
+    _, out_of = _doubling_scan(op, identity, ended[:, 0], acc[:, 0])
+    carry = _shifted(out_of, 1, identity)[:, None]
+    acc = jnp.where(ended, acc, op(acc, carry))
+    return acc.reshape(-1)[:n]
+
+
 def segment_sum_sorted(keys, values, valid, reduce_op: str = "sum"):
-    """Aggregate values of equal adjacent keys (input sorted by key).
+    """Aggregate values of equal adjacent keys (input sorted by key along
+    the last axis; leading axes are independent rows).
 
     Returns (unique_keys, aggregated, out_valid): one slot per first
-    occurrence, PAD elsewhere.  Pure jnp; the Pallas `segment_reduce` kernel
-    implements the same contract for the TPU deployment path.
+    occurrence, PAD elsewhere.  A run spans a first occurrence up to the
+    next one, so every run's total is a reverse segmented scan (flagged at
+    run ends) read at its first slot: no gather, scatter or sort.  Integer
+    sums wrap as int32 addition does in any order.  The Pallas
+    `segment_reduce` kernel implements the same contract for the TPU
+    deployment path.
     """
-    n = keys.shape[0]
+    if reduce_op not in ("sum", "max", "first"):
+        raise ValueError(reduce_op)
+    edge = jnp.ones_like(keys[..., :1], dtype=bool)
     first = jnp.concatenate(
-        [jnp.array([True]), keys[1:] != keys[:-1]]
+        [edge, keys[..., 1:] != keys[..., :-1]], axis=-1
     ) & valid
-    seg_id = jnp.cumsum(first.astype(jnp.int32)) - 1  # -1 before first valid
-    seg_id = jnp.where(valid, seg_id, n - 1)  # dump invalid into last slot
-    if reduce_op == "sum":
-        agg = jnp.zeros((n,), dtype=values.dtype).at[seg_id].add(
-            jnp.where(valid, values, 0)
-        )
-    elif reduce_op == "max":
-        agg = jnp.full((n,), jnp.iinfo(jnp.int32).min, dtype=values.dtype)
-        agg = agg.at[seg_id].max(
-            jnp.where(valid, values, jnp.iinfo(jnp.int32).min)
-        )
-    elif reduce_op == "first":
+    if reduce_op == "first":
         # The earliest value of each run in delivery order: the stable
         # sorts upstream put it at the first-occurrence slot, so the
         # aggregate IS the value already sitting there.  Order-dependent
         # by definition — hence not in COMBINABLE_OPS.
-        agg = jnp.zeros((n,), dtype=values.dtype).at[seg_id].add(
-            jnp.where(first, values, 0)
-        )
+        agg = values
     else:
-        raise ValueError(reduce_op)
-    # The aggregate for the segment starting at a first-occurrence position i
-    # is agg[seg_id[i]]; non-first slots are PAD.
+        op, identity = {
+            "sum": (jnp.add, 0),
+            "max": (jnp.maximum, jnp.iinfo(jnp.int32).min),
+        }[reduce_op]
+        run_end = jnp.concatenate([first[..., 1:], edge], axis=-1)
+        agg = _reverse_segmented_scan(
+            op, identity, run_end, jnp.where(valid, values, identity)
+        )
     out_keys = jnp.where(first, keys, PAD_KEY)
-    out_vals = jnp.where(first, agg[seg_id], 0)
+    out_vals = jnp.where(first, agg, 0)
     return out_keys, out_vals, first
 
 

@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sorted_rows
+
 from repro.mapreduce import JobConfig, get_shuffle_backend
 from repro.mapreduce.phases import (
     PAD_KEY,
@@ -48,6 +50,24 @@ class TestSegmentSumSortedMax:
         keys = jnp.asarray([1], jnp.int32)
         with pytest.raises(ValueError):
             segment_sum_sorted(keys, keys, keys != PAD_KEY, "mean")
+
+
+class TestSegmentSumSortedExact:
+    """Every row of each case, as a 1-D call, against a numpy group-by."""
+
+    @pytest.mark.parametrize("case", sorted_rows.CASES)
+    @pytest.mark.parametrize("op", sorted_rows.OPS)
+    def test_matches_numpy_group_by(self, op, case):
+        keys, values = sorted_rows.case(case)
+        for k, v in zip(keys, values):
+            ok, ov, first = segment_sum_sorted(
+                jnp.asarray(k), jnp.asarray(v), jnp.asarray(k != PAD_KEY), op
+            )
+            want_k, want_v, want_first = sorted_rows.group_by_row(k, v, op)
+            assert ok.dtype == ov.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(ok), want_k)
+            np.testing.assert_array_equal(np.asarray(ov), want_v)
+            np.testing.assert_array_equal(np.asarray(first), want_first)
 
 
 class TestBucketScatter:
