@@ -4,7 +4,8 @@ a closed loop of jobs, and check what the timed jobs produced.
 A cell names a configuration (``bench/configs/<config>.json``, whose
 ``app`` names ``bench/apps/<app>.py``) and a traffic mix
 (``bench/traffic/<traffic>.json``: ``mode``, the ``ExecutionPlan`` method
-that builds the job, and ``job``, its ``JobConfig`` fields).  Each
+that builds the job, and ``job``, its ``JobConfig`` fields).  A cell on
+several chips runs a mode that takes a mesh (``sharded``).  Each
 per-layer metric is read by ``bench/metrics/<metric>.py``.  So a new cell,
 deployment, mix or metric is new files and ``BENCHMARK.json`` entries.
 
@@ -20,18 +21,19 @@ from __future__ import annotations
 
 import functools
 import importlib.util
+import inspect
 import json
 import os
 import shutil
 import tempfile
 import time
-from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from pathlib import Path
 
 import numpy as np
 
 from bench import check
+from bench import scopes as sc
 from bench import trace as tr
 
 BENCH = Path(__file__).resolve().parent
@@ -119,54 +121,62 @@ def job_config(cell: Cell):
     return JobConfig(**cell.config["engine"], **cell.traffic["job"])
 
 
+def takes_mesh(mode) -> bool:
+    """Whether an ``ExecutionPlan`` mode runs on a device mesh."""
+    return "mesh" in inspect.signature(mode).parameters
+
+
+def mode_job(cell: Cell, plan, sharding, **kwargs):
+    """The traffic's ``mode`` of ``plan`` (with ``kwargs``), on the mesh of
+    ``sharding`` where the mode takes one."""
+    from jax.sharding import NamedSharding
+
+    mode = getattr(plan, cell.traffic["mode"])
+    if isinstance(sharding, NamedSharding):
+        return mode(sharding.mesh, **kwargs)
+    return mode(**kwargs)
+
+
 def build_entry(cell: Cell, devices):
     """The program's plan, the jitted job the window drives (the plan's
-    ``mode`` method), and where its input lives."""
-    from jax.sharding import SingleDeviceSharding
+    ``mode`` method), and where its input lives.
+
+    A mode that takes a mesh gets a 1-D ``workers`` mesh over the cell's
+    first ``chips`` devices, and its input is laid out in contiguous
+    blocks, one per chip, as HDFS leaves each node its own blocks of the
+    log.  The mesh's axis is ``Auto``: on an ``Explicit`` axis (the
+    default of ``jax.make_mesh``) ``sharded`` cannot take an input split
+    over the chips, as the update that pads it into the map tasks' splits
+    has no output sharding it can resolve.  Any other mode runs on one
+    chip, with its input whole there."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec, \
+        SingleDeviceSharding
 
     from repro.mapreduce import ExecutionPlan
 
     cell.app.validate(cell.config, cell.traffic["job"])
-    plan = ExecutionPlan(cell.app.make_app(cell.config), job_config(cell),
+    cfg = job_config(cell)
+    plan = ExecutionPlan(cell.app.make_app(cell.config), cfg,
                          cell.config["tokens"])
-    job = getattr(plan, cell.traffic["mode"])()
-    return plan, job, SingleDeviceSharding(devices[0])
-
-
-def _named(fn, name: str):
-    def named(*args):
-        return fn(*args)
-
-    named.__name__ = named.__qualname__ = name
-    return named
-
-
-def phase_programs(plan, mode: str, tokens, pool) -> dict:
-    """The mode's phase functions (``plan.<mode>_phase_fns()``, else
-    ``plan.phase_fns()``, which ``fused`` composes) jitted one by one as
-    ``bench_<phase>``: the programs whose device time the traced run reads
-    per phase.  Fusion across their boundaries is lost here.  Compiled in
-    ``pool``."""
-    import jax
-
-    args, lowered = (tokens,), {}
-    fns = getattr(plan, f"{mode}_phase_fns", plan.phase_fns)()
-    for phase, fn in fns.items():
-        named = _named(fn, f"bench_{phase}")
-        lowered[phase] = jax.jit(named).lower(*args)
-        out = jax.eval_shape(named, *args)
-        args = out[:2] if phase == "shuffle" else out
-    futures = {p: pool.submit(low.compile) for p, low in lowered.items()}
-    return {p: f.result() for p, f in futures.items()}
-
-
-def run_phases(programs: dict, tokens) -> None:
-    import jax
-
-    args = (tokens,)
-    for phase, prog in programs.items():
-        out = jax.block_until_ready(prog(*args))
-        args = out[:2] if phase == "shuffle" else out
+    mode = cell.traffic["mode"]
+    if not takes_mesh(getattr(plan, mode)):
+        if cell.chips > 1:
+            raise ValueError(f"{cell.name}: mode {mode!r} takes no mesh, "
+                             f"so it cannot run on {cell.chips} chips")
+        sharding = SingleDeviceSharding(devices[0])
+    elif cfg.num_workers != cell.chips:
+        raise ValueError(f"{cell.name}: num_workers={cfg.num_workers}, but "
+                         f"the cell has {cell.chips} chips")
+    elif cell.config["tokens"] % cell.chips:
+        raise ValueError(f"{cell.name}: tokens={cell.config['tokens']} do "
+                         f"not split evenly over {cell.chips} chips")
+    else:
+        mesh = jax.make_mesh((cell.chips,), ("workers",),
+                             axis_types=(jax.sharding.AxisType.Auto,),
+                             devices=devices[: cell.chips])
+        sharding = NamedSharding(mesh, PartitionSpec("workers"))
+    return plan, mode_job(cell, plan, sharding), sharding
 
 
 def closed_loop(job, tokens, seconds: float, fingerprint):
@@ -205,26 +215,33 @@ class Readings:
     ``read(readings) -> float | None``) reads from."""
 
     window: tr.Trace
-    phases: tr.Trace | None
     jobs: int
     window_s: float
     devices: list
+    #: device ms per job of each phase of the timed job, read from its own
+    #: ``mr.<phase>`` scopes and averaged over the chips used
+    #: (:func:`bench.scopes.mean_scope_ms`); empty where the trace holds
+    #: no run of the job
+    scopes: dict = field(default_factory=dict)
+    #: the device counters of one run of the mode's ``counters=True``
+    #: variant after the window, or None
+    counters: dict | None = None
 
     @property
     def busy_s(self) -> float:
         return tr.mean_busy_s(self.window, self.devices)
 
-    def phase_ms(self, phase: str):
-        """Device ms of one run of ``bench_<phase>``, or None."""
-        runs = tr.module_runs(self.phases, f"bench_{phase}") \
-            if self.phases else []
-        return 1e3 * sum(runs) / len(runs) if runs else None
-
 
 def _profiled(directory: str, fn, *args):
+    """``fn(*args)`` under the profiler, without its Python tracer (the
+    benchmark's ``TraceAnnotation`` spans stay) and without the programs'
+    HLO protos (the job's text is taken from the compiled job)."""
     import jax
 
-    jax.profiler.start_trace(directory)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(directory, profiler_options=options)
     try:
         return fn(*args)
     finally:
@@ -237,13 +254,22 @@ def _load_trace(directory: str) -> tr.Trace:
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
-        t_start: float) -> dict:
+        t_start: float, save: str | None = None) -> dict:
     """One run of ``cell`` on ``devices`` (the first ``cell.chips`` are
     used); ``t_start`` is the process's start on ``time.perf_counter``.
     Returns the result line's fields and, under ``_log``, what the run
-    prints on standard error."""
+    prints on standard error.  With ``save``, a traced run keeps its
+    window's ``.xplane.pb`` and the job's HLO text (``job.hlo.txt``,
+    without source locations) in that directory."""
     import jax
 
+    if trace:
+        # The job's scopes are read from its compiled text, so the traced
+        # run keys its programs by their metadata too: an executable
+        # compiled from a program that differs only in its scopes is
+        # never served in its place.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     used = list(devices[: cell.chips])
     used_ids = [d.id for d in used]
     setup, log = {}, []
@@ -272,15 +298,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     del out
     setup["warmup_s"] = time.perf_counter() - t
 
-    programs = {}
+    tdir = None
     if trace:
-        t = time.perf_counter()
-        with ThreadPoolExecutor(max_workers=4) as pool:
-            programs = phase_programs(plan, cell.traffic["mode"], tokens,
-                                      pool)
-        setup["phase_programs_s"] = time.perf_counter() - t
-
-    tdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+        hlo = compiled.as_text()
+        scopes, module = sc.scope_map(hlo), sc.module_of(hlo)
+        tdir = save or tempfile.mkdtemp(prefix="bench-trace-")
+        if save:
+            Path(save).mkdir(parents=True, exist_ok=True)
+            (Path(save) / "job.hlo.txt").write_text(sc.without_sources(hlo))
     try:
         setup_s = time.perf_counter() - t_start
         loop = functools.partial(closed_loop, compiled, tokens, seconds,
@@ -303,9 +328,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         drops = [int(d) for d in drops]
         host_tokens = np.asarray(tokens)
         check_s = time.perf_counter() - t
-        if programs:
-            _profiled(f"{tdir}/phases", run_phases, programs, tokens)
-        del loop, tokens, compiled, programs, job, plan
+        counters = None
+        if trace:
+            # The phase-boundary counters: one run of the mode's counting
+            # variant, after the window and the peak's reading.
+            stats = mode_job(cell, plan, sharding, counters=True)(tokens)[-1]
+            counters = {k: int(v) for k, v in jax.device_get(stats).items()
+                        if np.ndim(v) == 0}
+        del loop, tokens, compiled, job, plan
         t = time.perf_counter()
         keys, vals = cell.app.pairs(np, host_tokens, cell.config)
         ref_counts, ref_sums = check.exact(keys, vals,
@@ -315,11 +345,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
 
         readings = None
         if trace:
-            readings = Readings(_load_trace(f"{tdir}/window"),
-                                _load_trace(f"{tdir}/phases"),
-                                jobs, window_s, used_ids)
+            window = _load_trace(f"{tdir}/window")
+            readings = Readings(
+                window, jobs, window_s, used_ids,
+                sc.mean_scope_ms(window, used_ids, module, scopes), counters)
+            busy = [sc.busy_ms(window, d, module) for d in used_ids]
     finally:
-        if tdir:
+        if tdir and not save:
             shutil.rmtree(tdir, ignore_errors=True)
 
     compared = {
@@ -361,6 +393,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     log.append("memory " + " ".join(f"{k}={v}" for k, v in memory.items()))
     log.append("allocator " + " ".join(f"{k}={v}"
                                        for k, v in sorted(allocator.items())))
+    if readings is not None:
+        log.append(f"scopes module={module} " + " ".join(
+            f"{p}_ms={v}" for p, v in readings.scopes.items())
+            + f" sum_ms={sum(readings.scopes.values())} module_busy_ms="
+            + ",".join(str(b) for b in busy))
+        log.append("counters " + " ".join(
+            f"{k}={v}" for k, v in sorted((counters or {}).items())))
     log.append(f"window jobs={jobs} window_s={window_s:.4f} "
                f"check_s={check_s:.3f} failed={failed}")
     log += [f"compared {k}={v} limit={check.LIMITS[k]}"

@@ -8,21 +8,23 @@ events carry only the instruction's text, without that metadata, so the
 phase of an op comes from the compiled program's text
 (``compiled.as_text()``): :func:`scope_map`.  :func:`scope_ms` then reduces
 a trace (``bench.trace.Trace``) to device ms per phase per job, counting
-only ops inside the runs of the job's own module.
+only ops inside the runs of the job's own module.  A phase's time is the
+union of its ops' intervals, async copies' spans included, so the phases
+together cover the job's busy time.  The benchmark's ``--trace 1`` run
+(``bench.harness.run``) reads the per-layer metrics from these.
 
-Run as a script, it measures one cell on the chip the way a ``--trace 1``
-run would read these numbers (the benchmark's harness does not read them
-yet), and prints one JSON line:
+Run as a script, it makes one such traced run of a cell, on whatever
+devices JAX finds (the CPU too, where the trace holds no device op and
+so no scope), and prints its result line:
 
     python3 bench/scopes.py --workload exim-mainlog.m20r5 --seed 7 \\
         --seconds 45
 
-It times a closed loop of the cell's job untraced, then the same loop
-under the profiler, reduces the traced loop to ms per scope, and runs the
-job's ``counters=True`` variant once for the live-slot shares.  With
-``--tokens N`` the cell's input is cut to ``N`` tokens; ``--save DIR``
-keeps the traced loop's ``.xplane.pb`` and the job's HLO text there, and
-traces without the Python tracer and the HLO protos to keep them small.
+Standard error carries the run's ``scopes`` line (ms per scope, their
+sum, the job module's busy ms on each chip) and its ``counters`` line.
+With ``--tokens N`` the cell's input is cut to ``N`` tokens; ``--save
+DIR`` keeps the traced window's ``.xplane.pb`` and the job's HLO text
+there.
 """
 
 from __future__ import annotations
@@ -187,6 +189,15 @@ def scope_ms(trace: tr.Trace, device: int, module: str,
             for p, evs in sorted(groups.items())}
 
 
+def mean_scope_ms(trace: tr.Trace, devices, module: str,
+                  scopes: dict[str, str]) -> dict[str, float]:
+    """:func:`scope_ms` averaged over ``devices``: a phase that a device
+    did not run counts 0 there."""
+    per_device = [scope_ms(trace, d, module, scopes) for d in devices]
+    return {p: sum(s.get(p, 0.0) for s in per_device) / len(per_device)
+            for p in sorted({p for s in per_device for p in s})}
+
+
 def live_pct(counters: dict) -> dict[str, float]:
     """The shuffle's and the reduce's live pairs as a share of the slots
     they walk, from a ``counters=True`` job's stats."""
@@ -215,95 +226,6 @@ def without_sources(hlo_text: str) -> str:
                   text)
 
 
-def measure(cell, seed: int, seconds: float, devices, save: str | None):
-    """The numbers of one run; see the module's docstring."""
-    import functools
-    import shutil
-    import tempfile
-    from concurrent.futures import ThreadPoolExecutor
-
-    import jax
-
-    from bench import check, harness
-
-    used = list(devices[: cell.chips])
-    ids = [d.id for d in used]
-    plan, job, sharding = harness.build_entry(cell, used)
-    gen = jax.jit(functools.partial(cell.app.generate, cell.config),
-                  out_shardings=sharding)
-    tokens = jax.block_until_ready(gen(harness.seed_key(seed)))
-
-    # The job and its counting variant, compiled side by side.
-    counted = getattr(plan, cell.traffic["mode"])(counters=True)
-    t = time.perf_counter()
-    with ThreadPoolExecutor(max_workers=2) as pool:
-        compiled, counted = pool.map(
-            lambda p: p.lower(tokens).compile(), (job, counted))
-    compile_s = time.perf_counter() - t
-    hlo = compiled.as_text()
-    scopes, module = scope_map(hlo), module_of(hlo)
-
-    fingerprint = jax.jit(check.fingerprint)
-    out = jax.block_until_ready(compiled(tokens))
-    jax.block_until_ready(fingerprint(out[0], out[1]))
-    del out
-    setup_s = time.perf_counter() - T_START
-
-    loop = functools.partial(harness.closed_loop, compiled, tokens,
-                             seconds, fingerprint)
-    _, _, _, t0, done = loop()
-    untraced = {"jobs": len(done), "job_s": (done[-1] - t0) / len(done)}
-
-    options = jax.profiler.ProfileOptions()
-    if save:
-        options.python_tracer_level = 0
-        options.enable_hlo_proto = False
-    tdir = tempfile.mkdtemp(prefix="bench-scopes-")
-    try:
-        jax.profiler.start_trace(tdir, profiler_options=options)
-        try:
-            _, prints, drops, t0, done = loop()
-        finally:
-            jax.profiler.stop_trace()
-        traced = {"jobs": len(done), "job_s": (done[-1] - t0) / len(done)}
-        window = harness._load_trace(tdir)
-        if save:
-            Path(save).mkdir(parents=True, exist_ok=True)
-            path = sorted(Path(tdir).glob("**/*.xplane.pb"))[-1]
-            shutil.copy(path, Path(save) / "scoped.xplane.pb")
-            (Path(save) / "scoped.hlo.txt").write_text(without_sources(hlo))
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-
-    ok, ov, _, stats = counted(tokens)
-    same = int(fingerprint(ok, ov)) == int(prints[-1])
-    counters = {k: int(v) for k, v in jax.device_get(stats).items()}
-    del ok, ov
-
-    per_device = [scope_ms(window, d, module, scopes) for d in ids]
-    scoped = {p: sum(s.get(p, 0.0) for s in per_device) / len(ids)
-              for p in sorted({p for s in per_device for p in s})}
-    busy = [busy_ms(window, d, module) for d in ids]
-    return {
-        "device": {"platform": used[0].platform,
-                   "kind": used[0].device_kind, "count": len(devices)},
-        "module": module,
-        "instructions": len(scopes),
-        "instructions_scoped": sum(p != UNSCOPED for p in scopes.values()),
-        "untraced": untraced,
-        "traced": traced,
-        "module_busy_ms": (sum(busy) / len(busy)
-                           if None not in busy else None),
-        "scope_ms": scoped,
-        "scope_sum_ms": sum(scoped.values()),
-        "counters": counters,
-        "counted_output_same": same,
-        "dropped": max(int(d) for d in drops),
-        **live_pct(counters),
-        "setup": {"compile_both_s": compile_s, "setup_s": setup_s},
-    }
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -312,8 +234,8 @@ def main(argv=None) -> int:
     ap.add_argument("--tokens", type=int, default=None,
                     help="cut the cell's input to this many tokens")
     ap.add_argument("--save", default=None,
-                    help="directory for the traced loop's .xplane.pb and "
-                         "the job's HLO text")
+                    help="directory that keeps the traced window's "
+                         ".xplane.pb and the job's HLO text")
     args = ap.parse_args(argv)
 
     from bench import harness
@@ -324,8 +246,10 @@ def main(argv=None) -> int:
     harness.enable_compile_cache()
     import jax
 
-    result = measure(cell, args.seed, args.seconds, jax.devices(),
-                     args.save)
+    result = harness.run(cell, args.seed, args.seconds, True, jax.devices(),
+                         T_START, save=args.save)
+    for line in result.pop("_log"):
+        print(line, file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -66,3 +66,25 @@ def test_per_layer_metrics_name_known_cells_and_moves():
     for m in MANIFEST["per_layer"]:
         assert m["moves"] in e2e
         assert set(m.get("workloads", CELLS)) <= set(CELLS)
+
+
+def test_metric_sources_are_allowed():
+    for m in MANIFEST["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace"), m
+    for m in MANIFEST["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock"), m
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_multi_chip_cells_run_on_a_mesh(cell):
+    """A cell on several chips runs a mode that takes a mesh, with one
+    worker per chip and its input split evenly over them."""
+    from repro.mapreduce import ExecutionPlan
+
+    c = harness.resolve(cell)
+    if c.chips == 1:
+        return
+    assert harness.takes_mesh(getattr(ExecutionPlan, c.traffic["mode"]))
+    assert c.traffic["job"]["num_workers"] == c.chips
+    assert c.config["tokens"] % c.chips == 0

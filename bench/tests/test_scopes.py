@@ -7,17 +7,31 @@ runs in the benchmark's closed loop under ``bench.submit`` /
 source locations; recorded by ``python3 bench/scopes.py --workload
 wordcount-hibench.m20r5-combine --seed 3000000221 --seconds 0.02 --tokens
 65536 --save <dir>``, then gzipped, with the checkout's path in the ops'
-source stats replaced by ``<checkout>/``)."""
+source stats replaced by ``<checkout>/``), and the per-layer readers
+(``bench/metrics/``) on both."""
 
 import gzip
 from pathlib import Path
 
 import pytest
 
+from bench import harness
 from bench import scopes as sc
 from bench import trace as tr
+from bench.tests.conftest import ROOT
 
 DATA = Path(__file__).resolve().parent / "data"
+PHASES = ("map", "combine", "shuffle", "reduce")
+READERS = [f"{p}_scope_ms" for p in PHASES] + [
+    "unscoped_ms", "shuffle_live_pct", "reduce_live_pct", "device_idle_pct"]
+#: the Exim cell's counters (88,473,600 tokens, M=20, R=5, no combiner)
+EXIM_COUNTERS = {"shuffle.slots": 88_473_600, "shuffle.pairs": 29_491_200,
+                 "reduce.slots": 5 * 70_778_880, "reduce.pairs": 29_491_200}
+
+
+def read(name: str, readings):
+    path = ROOT / "bench" / "metrics" / f"{name}.py"
+    return harness.load_module(path).read(readings)
 
 HLO = """\
 HloModule jit_job, entry_computation_layout={(s32[8]{0})->s32[8]{0}}
@@ -114,6 +128,25 @@ def test_scope_ms_counts_only_the_jobs_own_runs():
     assert sc.scope_ms(t, 1, "jit_job", scopes) == {}
 
 
+def test_mean_scope_ms_averages_over_chips():
+    E = tr.Event
+    scopes = {"f.1": "map", "g.2": "unscoped"}
+    job = [E("jit_job(7)", 0, 1000)]
+    t = tr.Trace(ops={0: [E("%f.1 = s32[8] fusion(%x)", 0, 400)],
+                      1: [E("%f.1 = s32[8] fusion(%x)", 0, 200),
+                          E("%g.2 = s32[8] all-gather(%f.1)", 300, 100)]},
+                 modules={0: job, 1: job})
+    assert sc.mean_scope_ms(t, [0, 1], "jit_job", scopes) == pytest.approx(
+        {"map": 300e-6, "unscoped": 50e-6})
+    assert sc.mean_scope_ms(t, [0, 1], "jit_other", scopes) == {}
+
+
+def test_readers_with_nothing_to_read_return_nothing():
+    r = harness.Readings(tr.Trace(), jobs=0, window_s=1.0, devices=[0])
+    assert {name: read(name, r) for name in READERS} == dict.fromkeys(
+        READERS)
+
+
 def test_live_pct_from_counters():
     c = {"shuffle.slots": 88_473_600, "shuffle.pairs": 29_491_200,
          "reduce.slots": 5 * 70_778_880, "reduce.pairs": 29_491_200}
@@ -168,3 +201,24 @@ def test_recorded_scoped_job(recorded):
     assert len(tr.module_runs(trace, "fingerprint")) == 3
     all_ops = sum(b - a for a, b in tr.union(trace.ops[0])) / 1e6
     assert all_ops > busy * len(runs)
+
+
+def test_readers_on_the_recorded_job(recorded):
+    trace, hlo = recorded
+    module, scopes = sc.module_of(hlo), sc.scope_map(hlo)
+    own = sc.scope_ms(trace, 0, module, scopes)
+    r = harness.Readings(trace, jobs=3, window_s=1.0, devices=[0],
+                         scopes=sc.mean_scope_ms(trace, [0], module, scopes),
+                         counters=EXIM_COUNTERS)
+    got = {name: read(name, r) for name in READERS}
+    for p in PHASES:
+        assert got[f"{p}_scope_ms"] == own[p] > 0
+    assert got["unscoped_ms"] == 0.0
+    # the phases and the unscoped ops add up to the job's busy time, give
+    # or take the async copies that cross a phase boundary
+    busy = sc.busy_ms(trace, 0, module)
+    total = sum(got[f"{p}_scope_ms"] for p in PHASES) + got["unscoped_ms"]
+    assert busy <= total <= 1.05 * busy
+    assert got["shuffle_live_pct"] == pytest.approx(100 / 3)
+    assert got["reduce_live_pct"] == pytest.approx(100 / 12)
+    assert 0 < got["device_idle_pct"] < 100

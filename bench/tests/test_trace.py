@@ -77,7 +77,7 @@ def test_union_and_readings():
     assert tr.busy_ns(t, 0) == 200
     assert tr.idle_gaps(t, 0) == [["bench.wait", 250e-9],
                                   ["bench.wait", 160e-9]]
-    r = Readings(window=t, phases=t, jobs=2, window_s=1e-6, devices=[0, 1])
+    r = Readings(window=t, jobs=2, window_s=1e-6, devices=[0, 1])
     assert r.busy_s == pytest.approx(250e-9)
-    assert r.phase_ms("map") == pytest.approx(3.0)
-    assert r.phase_ms("reduce") is None
+    assert r.scopes == {} and r.counters is None
+    assert tr.module_runs(t, "bench_map") == pytest.approx([2e-3, 4e-3])

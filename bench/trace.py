@@ -109,7 +109,7 @@ def opcode(text: str) -> str:
 
 
 def module_name(text: str) -> str:
-    """``jit_bench_map(2775352640231577865)`` -> ``jit_bench_map``."""
+    """``jit_job(2775352640231577865)`` -> ``jit_job``."""
     return text.split("(", 1)[0]
 
 
@@ -146,7 +146,7 @@ def op_seconds(trace: Trace, device: int) -> dict[str, float]:
 
 def module_runs(trace: Trace, name: str) -> list[float]:
     """Durations in seconds of every run of the program ``name`` (the
-    jitted function's name, e.g. ``bench_map``) on the first device that
+    jitted function's name, e.g. ``job``) on the first device that
     ran it."""
     for d in trace.devices:
         runs = [e.dur_ns / 1e9 for e in trace.modules.get(d, [])
