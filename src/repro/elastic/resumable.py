@@ -276,8 +276,8 @@ class ResumableJob:
                 "map", wall_s,
                 tasks=hi - lo, waves=1, workers=before.workers,
                 pairs_emitted=int(pv.sum()),
-                records_in=min(self.input_len, hi * self.S)
-                - min(self.input_len, lo * self.S),
+                records_in=min(self.plan.rows, hi * self.S)
+                - min(self.plan.rows, lo * self.S),
                 cpu_s=cpu_s, cpu_workers=_NCPU,
             )
         elif before.combined != c_after.combined:

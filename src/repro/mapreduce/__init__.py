@@ -1,5 +1,5 @@
 """TPU-native MapReduce substrate: phase pipeline + pluggable backends +
-the paper's two applications.
+the paper's two applications and the UserVisits aggregation.
 
 Layering (see ARCHITECTURE.md):
 
@@ -8,7 +8,7 @@ Layering (see ARCHITECTURE.md):
     plan.py     — ExecutionPlan: the ONE lowering into canonical wave
                   steppers; fused/traced/sharded/resumable are modes
     engine.py   — JobConfig/MapReduceApp + thin build_job mode selectors
-    apps.py     — WordCount and Exim mainlog parsing
+    apps.py     — WordCount, Exim mainlog parsing, UserVisits aggregation
     datagen.py  — synthetic corpora
 """
 
@@ -31,7 +31,8 @@ from repro.mapreduce.backends import (
     register_reduce_backend,
     register_shuffle_backend,
 )
-from repro.mapreduce.apps import eximparse, wordcount, RECORD_WIDTH
+from repro.mapreduce.apps import eximparse, uservisits, wordcount, \
+    RECORD_WIDTH
 from repro.mapreduce.datagen import exim_mainlog, wordcount_corpus
 
 __all__ = [
@@ -51,6 +52,7 @@ __all__ = [
     "register_reduce_backend",
     "register_shuffle_backend",
     "eximparse",
+    "uservisits",
     "wordcount",
     "RECORD_WIDTH",
     "exim_mainlog",

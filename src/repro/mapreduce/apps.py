@@ -1,4 +1,5 @@
-"""The paper's two benchmark applications, on the TPU MapReduce engine.
+"""The paper's two benchmark applications, and the Pavlo et al.
+aggregation, on the TPU MapReduce engine.
 
 * **WordCount** — each map task takes a split of word-ids and emits
   ``<word, 1>``; reducers sum per word.  (Paper §V.A, refs [33-34].)
@@ -8,8 +9,13 @@
   ``[txn_id, event_type, size]``; map emits ``<txn_id, packed(event, size)>``
   and reducers aggregate per transaction (event count + total bytes packed in
   one int32).  (Paper §V.A, ref [35].)
+* **UserVisits aggregation** — ``SELECT sourceIP, SUM(adRevenue) FROM
+  UserVisits GROUP BY sourceIP`` (Pavlo et al., SIGMOD 2009, §4.3; HiBench
+  ``sql/aggregation``) over a table of fixed-width int32 rows: the app
+  declares its row width, so the plan splits the table on rows and the map
+  emits one pair per row.
 
-Both apps are pure `jnp` map functions with static output sizes, as the
+All three are pure `jnp` map functions with static output sizes, as the
 engine requires.
 """
 
@@ -18,6 +24,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.mapreduce.engine import MapReduceApp, PAD_KEY
+from repro.mapreduce.phases import LANES
 
 # ---------------------------------------------------------------------------
 # WordCount
@@ -36,7 +43,6 @@ def wordcount(vocab_size: int = 4096) -> MapReduceApp:
         name="wordcount",
         key_space=vocab_size,
         map_fn=_wordcount_map,
-        pairs_per_token=1,
         reduce_op="sum",
     )
 
@@ -81,6 +87,50 @@ def eximparse(n_transactions: int = 1024) -> MapReduceApp:
         name="eximparse",
         key_space=n_transactions,
         map_fn=_eximparse_map,
-        pairs_per_token=1,
+        reduce_op="sum",
+    )
+
+
+# ---------------------------------------------------------------------------
+# UserVisits aggregation
+# ---------------------------------------------------------------------------
+
+def _column(tokens, width: int, col: int):
+    """Column ``col`` of a split of ``width``-token rows, read from a
+    lane-dense ``(rows * width / LANES, LANES)`` view (row ``i * k + j`` of
+    ``k = LANES / width`` rows at lane ``j * width + col``) where the rows
+    tile the lanes, else from the flat split by stride; a ``(rows, width)``
+    view would pad each row out to a whole vector register."""
+    if LANES % width == 0 and tokens.size % LANES == 0:
+        return tokens.reshape(-1, LANES)[:, col::width].reshape(-1)
+    return tokens.reshape(-1)[col::width]
+
+
+def _uservisits_map(width: int, ip_col: int, revenue_col: int):
+    def map_fn(tokens, valid):
+        """<row> -> <sourceIP, adRevenue>, one pair per row."""
+        ip = _column(tokens, width, ip_col)
+        revenue = _column(tokens, width, revenue_col)
+        keys = jnp.where(valid, ip, PAD_KEY)
+        values = jnp.where(valid, revenue, 0).astype(jnp.int32)
+        return keys, values, valid
+
+    return map_fn
+
+
+def uservisits(key_space: int, record_width: int, ip_col: int,
+               revenue_col: int) -> MapReduceApp:
+    """``SUM(adRevenue) GROUP BY sourceIP`` over UserVisits rows of
+    ``record_width`` int32 tokens: ``sourceIP`` interned to an id below
+    ``key_space`` at token ``ip_col``, ``adRevenue`` in int32 cents at
+    token ``revenue_col``."""
+    for col in (ip_col, revenue_col):
+        if not 0 <= col < record_width:
+            raise ValueError(f"column {col} outside a row of {record_width}")
+    return MapReduceApp(
+        name="uservisits",
+        key_space=key_space,
+        map_fn=_uservisits_map(record_width, ip_col, revenue_col),
+        record_width=record_width,
         reduce_op="sum",
     )

@@ -95,14 +95,28 @@ class MapReduceApp:
     commutative+associative and therefore combiner-eligible; ``first``
     (keep the earliest value per key in delivery order) is order-dependent
     and only legal with the combiner off.
+
+    The input is a flat int32 stream of fixed-width records of
+    ``record_width`` tokens.  The plan cuts it on record boundaries into
+    M splits of ``ceil(rows / M)`` records each, so the map's pair slots,
+    and every capacity after them, follow from records, not tokens.
     """
 
     name: str
     key_space: int
-    # map_fn(tokens (S,), valid (S,)) -> keys (P,), values (P,), valid (P,)
+    # map_fn(tokens, valid (S,)) -> keys (P,), values (P,), valid (P,)
+    # with P = S * pairs_per_record: a split of S whole records with one
+    # validity flag per record; tokens holds its S * record_width tokens
+    # in order, flat or, for records wider than a token, as rows of
+    # phases.LANES tokens where they fill whole rows
     map_fn: Callable
-    pairs_per_token: int = 1
+    record_width: int = 1  # tokens per input record
+    pairs_per_record: int = 1  # pair slots the map emits per record slot
     reduce_op: str = "sum"  # "sum" | "max" | "first"
+
+    def __post_init__(self):
+        if self.record_width < 1 or self.pairs_per_record < 1:
+            raise ValueError(f"bad record shape {self}")
 
 
 def build_job(app: MapReduceApp, cfg: JobConfig, input_len: int,

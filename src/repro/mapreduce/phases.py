@@ -20,8 +20,9 @@ combiner, partition, and reduce logic — one in ``build_job`` and one in
 * :func:`scoped`            — a phase function under its named scope
   ``mr.<phase>``, so a profile of any program built from it can say which
   phase each device op belongs to;
-* :func:`pair_counts` / :func:`shuffle_counts` / :func:`partition_counts` /
-  :func:`output_counts` — the device counters at each phase boundary.
+* :func:`map_counts` / :func:`pair_counts` / :func:`shuffle_counts` /
+  :func:`partition_counts` / :func:`output_counts` — the device counters
+  at each phase boundary.
 
 Everything is pure ``jnp`` with static shapes, so every phase composes
 under ``jit``, ``vmap``, ``scan``, and ``shard_map``.
@@ -90,6 +91,16 @@ def pair_counts(phase: str, pvalid) -> dict:
     return {f"{phase}.pairs": _total(pvalid)}
 
 
+def map_counts(record_valid, pvalid) -> dict:
+    """Through the map: the live records it parsed, the pair slots it
+    emits and spill-sorts (live or not), and the live pairs among them."""
+    return {
+        "map.records": _total(record_valid),
+        "map.slots": jnp.int32(pvalid.size),
+        **pair_counts("map", pvalid),
+    }
+
+
 def shuffle_counts(keys, pvalid, dropped) -> dict:
     """Through the shuffle: the pair slots it sorts (live or not), the
     live pairs among them, and the pairs it dropped."""
@@ -140,9 +151,12 @@ def hash_to_reducer(keys, num_reducers: int):
     return (h % jnp.uint32(num_reducers)).astype(jnp.int32)
 
 
+#: lanes of a TPU vector register
+LANES = 128
+
 #: width of the blocks the segmented scan runs within before it carries
 #: across them: one TPU vector register's lanes
-SCAN_BLOCK = 128
+SCAN_BLOCK = LANES
 
 
 def _shifted(x, s: int, fill):
@@ -243,7 +257,9 @@ def segment_sum_sorted(keys, values, valid, reduce_op: str = "sum"):
 def run_map_task(app, cfg, tokens, valid):
     """One map task: startup + map_fn + local spill sort.
 
-    tokens/valid: (S,).  Returns keys/values/pvalid of shape (P,).  The
+    tokens: the split's S records, S * record_width tokens (flat, or as
+    rows of ``LANES``; see ``ExecutionPlan._split``); valid: (S,), one
+    flag per record.  Returns keys/values/pvalid of shape (P,).  The
     map-side combiner is *not* applied here — it is its own fenced stage
     (:func:`combine_rows`, run by the plan between map and shuffle) so it
     can be wall-clocked, counted, and checkpointed at a wave boundary.
@@ -260,7 +276,8 @@ def run_map_task(app, cfg, tokens, valid):
 def map_phase(app, cfg, splits, split_valid):
     """Run map tasks in waves of W workers.
 
-    splits: (waves, W, S) int32; split_valid: (waves, W, S) bool.
+    splits: (waves, W, <one split>) int32; split_valid: (waves, W, S)
+    bool.
     Returns keys/values/valid of shape (waves, W, P).
     """
 

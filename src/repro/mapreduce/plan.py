@@ -29,7 +29,9 @@ buffers**, and every entry point is a *mode* over that one plan:
 
 The canonical stepper contract (all shapes static per plan):
 
-* ``prep(tokens)``                        → ``(splits (M, S), valid (M, S))``
+* ``prep(tokens)``                        → ``(splits (M, S·w), valid (M, S))``:
+  the record-aligned split, ``S = ceil(rows / M)`` whole records of
+  ``w = app.record_width`` tokens per task, one validity flag per record;
 * ``map_step(W)(splits, valid, bk, bv, bp, start)``
                                           → updated ``(M, P)`` accumulators
 * ``combine_step()(bk, bv, bp)``          → compacted ``(M, Pc)`` task rows
@@ -94,33 +96,36 @@ def _pad_rows(arr, n_extra: int, fill):
     return jnp.concatenate([arr, pad], axis=0)
 
 
-#: the device counters of each phase, from its program's arguments and
-#: outputs (the functions of :mod:`repro.mapreduce.phases`); the shuffle's
-#: also counts the partitions it hands the reduce
-_PHASE_COUNTS = {
-    "map": lambda args, out: phases.pair_counts("map", out[2]),
-    "combine": lambda args, out: phases.pair_counts("combine", out[2]),
-    "shuffle": lambda args, out: {
-        **phases.shuffle_counts(args[0], args[2], out[2]),
-        **phases.partition_counts(out[0]),
-    },
-    "reduce": lambda args, out: phases.output_counts(out[0]),
-}
+def _phase_counts(records) -> dict:
+    """The device counters of each phase, from its program's arguments and
+    outputs (the functions of :mod:`repro.mapreduce.phases`).
+    ``records(*map_args)`` is the record validity of what the map reads;
+    the shuffle's counters also count the partitions it hands the
+    reduce."""
+    return {
+        "map": lambda args, out: phases.map_counts(records(*args), out[2]),
+        "combine": lambda args, out: phases.pair_counts("combine", out[2]),
+        "shuffle": lambda args, out: {
+            **phases.shuffle_counts(args[0], args[2], out[2]),
+            **phases.partition_counts(out[0]),
+        },
+        "reduce": lambda args, out: phases.output_counts(out[0]),
+    }
 
 
-def _compose(fns: dict, counters: bool = False):
+def _compose(fns: dict, counts: dict | None = None):
     """The job of a phase dict (:meth:`ExecutionPlan.phase_fns` and kin):
     map, the combine if present, shuffle, reduce.  ``job(*inputs) -> (ok,
-    ov, dropped)``; with ``counters`` also a dict of the phases' int32
-    device counters, computed in the same program."""
+    ov, dropped)``; given ``counts`` (:func:`_phase_counts`) also a dict of
+    the phases' int32 device counters, computed in the same program."""
 
     def job(*inputs):
         stats = {}
 
         def run(phase, *args):
             out = fns[phase](*args)
-            if counters:
-                stats.update(_PHASE_COUNTS[phase](args, out))
+            if counts is not None:
+                stats.update(counts[phase](args, out))
             return out
 
         bufs = run("map", *inputs)
@@ -128,21 +133,22 @@ def _compose(fns: dict, counters: bool = False):
             bufs = run("combine", *bufs)
         pk, pv, dropped = run("shuffle", *bufs)
         ok, ov = run("reduce", pk, pv)
-        if counters:
+        if counts is not None:
             return ok, ov, dropped, stats
         return ok, ov, dropped
 
     return job
 
 
-def _counted(phase: str, fn):
+def _counted(count, fn):
     """``fn`` jitted as one fenced phase program that also returns the
-    phase's device counters: ``prog(*args) -> (out, counts)``."""
+    phase's device counters (``count``, one entry of
+    :func:`_phase_counts`): ``prog(*args) -> (out, counts)``."""
 
     @functools.wraps(fn)
     def prog(*args):
         out = fn(*args)
-        return out, _PHASE_COUNTS[phase](args, out)
+        return out, count(args, out)
 
     return jax.jit(prog)
 
@@ -192,8 +198,16 @@ class ExecutionPlan:
             )
         self.M = cfg.num_mappers
         self.R = cfg.num_reducers
-        self.S = math.ceil(self.input_len / self.M)
-        self.P = self.S * app.pairs_per_token
+        if self.input_len % app.record_width:
+            raise ValueError(
+                f"input of {self.input_len} tokens is not whole records of "
+                f"{app.record_width} tokens"
+            )
+        #: input records, and the records of each map task's split
+        self.rows = self.input_len // app.record_width
+        self.S = math.ceil(self.rows / self.M)
+        #: pair slots each map task emits
+        self.P = self.S * app.pairs_per_record
         #: combined per-task row width (static distinct-key bound)
         self.combine_cap = phases.combine_capacity(self.P, app.key_space)
         #: column width of the task rows entering the shuffle barrier
@@ -219,6 +233,9 @@ class ExecutionPlan:
         self._jit_pipelined: dict[tuple[int, int, bool], callable] = {}
         self._cache_hits = 0
         self._cache_misses = 0
+        # The map of every mode but the fused mesh program reads the
+        # whole input, split as :meth:`_split` splits it.
+        self._counts = _phase_counts(lambda tokens: self._valid(self.M))
 
     # ------------------------------------------------------------- metadata
 
@@ -239,6 +256,8 @@ class ExecutionPlan:
         W = self.cfg.num_workers if workers is None else int(workers)
         return {
             "input_len": self.input_len,
+            "record_width": self.app.record_width,
+            "records": self.rows,
             "mappers": self.M,
             "reducers": self.R,
             "workers": W,
@@ -256,20 +275,43 @@ class ExecutionPlan:
 
     # ------------------------------------------------- raw stepper builders
 
+    def _valid(self, tasks: int):
+        """``(tasks, S)``: which record slots of ``tasks`` splits hold a
+        record of the input."""
+        return (jnp.arange(tasks * self.S) < self.rows).reshape(
+            tasks, self.S
+        )
+
+    def _split(self, tokens, tasks: int):
+        """The one record-aligned input split every mode maps: ``tasks``
+        splits of ``S`` whole records each, ``(tasks, S * w)`` tokens, and
+        their record validity ``(tasks, S)``.  Record slots past the
+        input's last record are zero and invalid.
+
+        A split of records wider than a token whose tokens fill whole
+        vector registers is laid out as ``(tasks, S * w / LANES, LANES)``:
+        the flat input's own tiling, so the split is a view of the input.
+        A 2-D split would be tiled by 8 tasks on the TPU, a copy of the
+        whole input padded to a multiple of 8 tasks."""
+        width = self.app.record_width
+        padded = jnp.zeros((tasks * self.S * width,), jnp.int32).at[
+            :self.input_len
+        ].set(tokens)
+        valid = self._valid(tasks)
+        shape = (tasks, self.S * width)
+        if width > 1 and self.S * width % phases.LANES == 0:
+            shape = (tasks, self.S * width // phases.LANES, phases.LANES)
+        return padded.reshape(shape), valid
+
     def _prep_fn(self):
-        M, S, input_len = self.M, self.S, self.input_len
+        M, input_len = self.M, self.input_len
 
         def prep(tokens):
             if tokens.shape != (input_len,):
                 raise ValueError(
                     f"expected ({input_len},), got {tokens.shape}"
                 )
-            pad_to = M * S
-            padded = jnp.zeros((pad_to,), jnp.int32).at[:input_len].set(
-                tokens
-            )
-            valid = (jnp.arange(pad_to) < input_len).reshape(M, S)
-            return padded.reshape(M, S), valid
+            return self._split(tokens, M)
 
         return prep
 
@@ -730,8 +772,11 @@ class ExecutionPlan:
         device scalars ``map.pairs``, ``combine.pairs`` (with the
         combiner), ``shuffle.slots`` / ``.pairs`` / ``.dropped`` and
         ``reduce.slots`` / ``.pairs`` / ``.segments``, counted in the same
-        program; the outputs are the same."""
-        return jax.jit(_compose(self.phase_fns(workers), counters))
+        program, and the map's ``map.records`` (live records parsed) and
+        ``map.slots`` (pair slots emitted and spill-sorted); the outputs
+        are the same."""
+        counts = self._counts if counters else None
+        return jax.jit(_compose(self.phase_fns(workers), counts))
 
     def pipelined(self, workers: int | None = None,
                   depth: int | None = None, counters: bool = False):
@@ -755,7 +800,8 @@ class ExecutionPlan:
             self._cache_hits += 1
             return self._jit_pipelined[key]
         self._cache_misses += 1
-        jitted = jax.jit(_compose(self.pipelined_phase_fns(W, D), counters))
+        counts = self._counts if counters else None
+        jitted = jax.jit(_compose(self.pipelined_phase_fns(W, D), counts))
         self._jit_pipelined[key] = jitted
         return jitted
 
@@ -778,7 +824,7 @@ class ExecutionPlan:
         """
         D = (getattr(self.cfg, "overlap_depth", 1)
              if depth is None else int(depth))
-        progs = {p: _counted(p, f)
+        progs = {p: _counted(self._counts[p], f)
                  for p, f in self.pipelined_phase_fns(workers, D).items()}
         m = self.meta(workers)
         pair_bytes = phases.PAIR_BYTES
@@ -803,8 +849,8 @@ class ExecutionPlan:
             trace.record_phase(
                 "map", dt,
                 tasks=m["mappers"], waves=m["map_waves"],
-                records_in=m["input_len"],
-                pairs_emitted=c["map.pairs"], pairs_capacity=m["n_pairs"],
+                records_in=c["map.records"],
+                pairs_emitted=c["map.pairs"], pairs_capacity=c["map.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
@@ -920,7 +966,7 @@ class ExecutionPlan:
             # The sharded path's structural shuffle IS the mesh collective.
             shuffle = _backends.SHUFFLE_BACKENDS["all_to_all"]
         reduce_backend = self.reduce_backend
-        M, R, S, P = self.M, self.R, self.S, self.P
+        M, R, P = self.M, self.R, self.P
         input_len = self.input_len
         waves_m = cfg.map_waves
         waves_r = cfg.reduce_waves
@@ -936,6 +982,7 @@ class ExecutionPlan:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P_
 
+        spec1 = P_(axis)  # the map's splits, of any rank
         spec2 = P_(axis, None)
         spec3 = P_(axis, None, None)
         replicated = NamedSharding(mesh, P_())
@@ -949,20 +996,16 @@ class ExecutionPlan:
             )
 
         def prep(tokens):
-            pad_to = M_pad * S
-            padded = jnp.zeros((pad_to,), jnp.int32).at[:input_len].set(
-                tokens
-            )
-            valid = (jnp.arange(pad_to) < input_len)
             # Worker-major task layout: worker w owns tasks w, w+W, ...
-            splits = padded.reshape(waves_m, W, S).transpose(1, 0, 2)
-            vsplit = valid.reshape(waves_m, W, S).transpose(1, 0, 2)
-            return splits, vsplit
+            return tuple(
+                jnp.swapaxes(x.reshape(waves_m, W, *x.shape[1:]), 0, 1)
+                for x in self._split(tokens, M_pad)
+            )
 
-        def w_map(splits, valid):  # (1(worker), waves, S) local shards
+        def w_map(splits, valid):  # (1(worker), waves, ...) local shards
             # Local map waves: reuse the shared map phase with W_local = 1.
-            sp = splits[0][:, None, :]   # (waves, 1, S)
-            va = valid[0][:, None, :]
+            sp = splits[0][:, None]   # (waves, 1, <one split>)
+            va = valid[0][:, None]
             k, v, pv = map_phase(app, cfg, sp, va)
             return (
                 k.reshape(1, n_local),
@@ -1029,8 +1072,10 @@ class ExecutionPlan:
 
         if recorder is None:
             # Fused single mesh program (the zero-overhead deployment
-            # path): all phases in one shard_map body.
-            worker_job = _compose(wfns, counters)
+            # path): all phases in one shard_map body, whose map reads
+            # this worker's splits and their record validity.
+            worker_job = _compose(wfns, _phase_counts(
+                lambda splits, valid: valid) if counters else None)
 
             def worker(splits, valid):
                 if not counters:
@@ -1042,7 +1087,7 @@ class ExecutionPlan:
             out_specs = (spec3, spec3, spec2)
             if counters:
                 out_specs += (P_(axis),)
-            shard_fn = smap(worker, (spec3, spec3), out_specs)
+            shard_fn = smap(worker, (spec1, spec1), out_specs)
 
             if not counters:
                 def plain(tokens):
@@ -1074,7 +1119,7 @@ class ExecutionPlan:
         # wall-clocked, its counters reduced across shards on the device.
         pair_bytes = phases.PAIR_BYTES
         specs = {
-            "map": ((spec3, spec3), (spec2, spec2, spec2)),
+            "map": ((spec1, spec1), (spec2, spec2, spec2)),
             "combine": ((spec2, spec2, spec2), (spec2, spec2, spec2)),
             "shuffle": ((spec2, spec2, spec2), (spec3, spec3, spec2)),
             "reduce": ((spec3, spec3), (spec3, spec3)),
@@ -1082,7 +1127,8 @@ class ExecutionPlan:
         mesh_fns = {p: smap(f, *specs[p]) for p, f in wfns.items()}
         map_fn = mesh_fns["map"]
         mesh_fns["map"] = lambda tokens: map_fn(*prep(tokens))
-        progs = {p: _counted(p, f) for p, f in mesh_fns.items()}
+        progs = {p: _counted(self._counts[p], f)
+                 for p, f in mesh_fns.items()}
 
         def traced_job(tokens):
             trace = recorder.start_job(app.name, cfg, input_len)
@@ -1101,8 +1147,8 @@ class ExecutionPlan:
             trace.record_phase(
                 "map", dt,
                 tasks=M, waves=waves_m, workers=W,
-                records_in=input_len,
-                pairs_emitted=c["map.pairs"], pairs_capacity=W * n_local,
+                records_in=c["map.records"],
+                pairs_emitted=c["map.pairs"], pairs_capacity=c["map.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 

@@ -21,6 +21,7 @@ from repro.mapreduce import (
     JobConfig,
     eximparse,
     phases,
+    uservisits,
     wordcount,
     wordcount_corpus,
 )
@@ -49,9 +50,20 @@ def _exim_log(n_msg: int = 40, seed: int = 5) -> np.ndarray:
     return rec.astype(np.int32).reshape(-1)
 
 
+def _uservisits_table(rows: int = 90, seed: int = 7) -> np.ndarray:
+    """Rows of 32 random tokens, sourceIP (token 0) below 64, adRevenue
+    (token 14) in cents."""
+    rng = np.random.default_rng(seed)
+    t = rng.integers(0, 2**31, size=(rows, 32))
+    t[:, 0] = rng.integers(0, 64, rows)
+    t[:, 14] = rng.integers(1, 100_001, rows)
+    return t.astype(np.int32).reshape(-1)
+
+
 APPS = {
     "wordcount": (wordcount(53), CORPUS),
     "exim": (eximparse(1024), _exim_log()),
+    "uservisits": (uservisits(64, 32, 0, 14), _uservisits_table()),
 }
 
 
@@ -81,7 +93,7 @@ def _job(plan, mode, **kw):
 
 @pytest.mark.parametrize("mode", ["fused", "pipelined"])
 @pytest.mark.parametrize("combiner", [False, True])
-@pytest.mark.parametrize("app_name", ["wordcount", "exim"])
+@pytest.mark.parametrize("app_name", ["wordcount", "exim", "uservisits"])
 def test_scope_map_covers_the_compiled_job(app_name, combiner, mode):
     # M=5 over W=1 keeps pipelined(depth=2)'s last wave group ragged.
     plan, corpus = _plan(app_name, combiner=combiner)
@@ -118,7 +130,10 @@ def _numpy_counts(plan, corpus) -> dict:
     """The counters, counted by numpy on each phase's buffers."""
     fns = {p: jax.jit(f) for p, f in plan.phase_fns().items()}
     bk, bv, bp = fns["map"](corpus)
-    want = {"map.pairs": int(np.asarray(bp).sum())}
+    _, valid = jax.jit(plan.prep())(corpus)
+    want = {"map.pairs": int(np.asarray(bp).sum()),
+            "map.slots": np.asarray(bp).size,
+            "map.records": int(np.asarray(valid).sum())}
     if "combine" in fns:
         bk, bv, bp = fns["combine"](bk, bv, bp)
         want["combine.pairs"] = int(np.asarray(bp).sum())
@@ -142,6 +157,7 @@ CASES = {
     "exim": dict(app_name="exim"),
     "exim-a2a-combine": dict(app_name="exim", combiner=True, num_workers=2,
                              shuffle_backend="all_to_all"),
+    "uservisits": dict(app_name="uservisits"),
     "overflow": dict(app_name="wordcount", num_reducers=4,
                      capacity_factor=1.0),
 }
@@ -166,6 +182,8 @@ def test_counters_are_exact_and_leave_the_output_alone(case):
         assert all(v.dtype == np.int32 and v.shape == ()
                    for v in stats.values()), mode
         assert {k: int(v) for k, v in stats.items()} == want, mode
+        assert want["map.slots"] == plan.M * plan.P
+        assert want["map.records"] == plan.rows
 
 
 @pytest.mark.parametrize("combiner", [False, True])
@@ -177,6 +195,8 @@ def test_traced_records_the_device_counters(combiner):
     plan.traced(recorder)(corpus)
     trace = recorder.last
     assert trace.counter("map", "pairs_emitted") == c["map.pairs"]
+    assert trace.counter("map", "records_in") == c["map.records"]
+    assert trace.counter("map", "pairs_capacity") == c["map.slots"]
     if combiner:
         assert trace.counter("combine", "pairs_out") == c["combine.pairs"]
     assert trace.counter("shuffle", "pairs_in") == c["shuffle.pairs"]
@@ -227,6 +247,8 @@ def test_sharded_counts_what_the_emulated_collective_counts(combiner):
     plan.sharded(mesh, recorder=recorder)(corpus)
     trace = recorder.last
     assert trace.counter("map", "pairs_emitted") == want["map.pairs"]
+    assert trace.counter("map", "records_in") == want["map.records"]
+    assert trace.counter("map", "pairs_capacity") == want["map.slots"]
     assert trace.counter("shuffle", "pairs_out") == want["reduce.pairs"]
     assert trace.counter("reduce", "segments_out") == \
         want["reduce.segments"]
@@ -256,6 +278,7 @@ for combiner in (False, True):
     *_, got = plan.sharded(mesh, counters=True)(corpus)
     got = {k: int(got[k]) for k in want}
     # the mesh walks whole waves: 2 x 4 task rows and 2 x 4 reducer rows
+    assert got.pop("map.slots") == 8 * plan.P
     assert got.pop("shuffle.slots") == 8 * plan.shuffle_width
     assert got.pop("reduce.slots") == 8 * plan.partition_cap(4)
     assert got == {k: v for k, v in want.items() if "slots" not in k}
@@ -263,6 +286,8 @@ for combiner in (False, True):
     plan.sharded(mesh, recorder=rec)(corpus)
     t = rec.last
     assert t.counter("map", "pairs_emitted") == want["map.pairs"]
+    assert t.counter("map", "records_in") == want["map.records"]
+    assert t.counter("map", "pairs_capacity") == 8 * plan.P
     assert t.counter("shuffle", "pairs_in") == want["shuffle.pairs"]
     assert t.counter("shuffle", "pairs_out") == want["reduce.pairs"]
     assert t.counter("reduce", "segments_out") == want["reduce.segments"]
@@ -278,3 +303,22 @@ def test_sharded_counters_on_four_workers():
     r = subprocess.run([sys.executable, "-c", _FOUR_WORKERS], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-3000:]
+
+
+def test_map_live_pct_reads_the_map_counters():
+    from types import SimpleNamespace
+
+    from bench import harness
+
+    reader = harness.load_module(harness.BENCH / "metrics" /
+                                 "map_live_pct.py")
+    plan, corpus = _plan("exim")
+    *_, stats = plan.fused(counters=True)(corpus)
+    counters = {k: int(v) for k, v in stats.items()}
+    assert reader.read(SimpleNamespace(counters=counters)) == \
+        pytest.approx(100 / 3, rel=0.05)
+    assert reader.read(SimpleNamespace(counters={
+        "map.pairs": 5, "map.slots": 20})) == 25.0
+    # a program that counts no map slots, and a run without counters
+    assert reader.read(SimpleNamespace(counters={"map.pairs": 5})) is None
+    assert reader.read(SimpleNamespace(counters=None)) is None

@@ -1,0 +1,207 @@
+"""Record-granular map output: an app that declares its record width is
+split on record boundaries, with any row count and any M, and its map emits
+``pairs_per_record`` pair slots per record slot, in every mode.
+
+The app under test is the UserVisits aggregation (``SELECT sourceIP,
+SUM(adRevenue) GROUP BY sourceIP``) over seeded tables of 32-token rows,
+checked against a numpy GROUP BY SUM.
+"""
+
+import math
+import subprocess
+import sys
+from collections import Counter
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from repro.mapreduce import (
+    ExecutionPlan,
+    JobConfig,
+    collect_results,
+    uservisits,
+    wordcount,
+    wordcount_corpus,
+)
+from repro.telemetry import PhaseRecorder
+
+ROOT = Path(__file__).resolve().parents[1]
+WIDTH, IP, REVENUE, KEYS = 32, 0, 14, 512
+APP = uservisits(KEYS, WIDTH, IP, REVENUE)
+
+
+def _table(rows: int, seed: int = 3) -> np.ndarray:
+    """``rows`` seeded rows of random tokens, flat, with sourceIP ids below
+    ``KEYS`` and adRevenue cents in [1, 100000]."""
+    rng = np.random.default_rng(seed)
+    t = rng.integers(-2**31, 2**31, size=(rows, WIDTH), dtype=np.int64)
+    t[:, IP] = rng.integers(0, KEYS, rows)
+    t[:, REVENUE] = rng.integers(1, 100_001, rows)
+    return t.astype(np.int32).reshape(-1)
+
+
+def _group_by_sum(table: np.ndarray) -> dict[int, int]:
+    rows = table.reshape(-1, WIDTH)
+    sums = np.bincount(rows[:, IP], weights=rows[:, REVENUE].astype(
+        np.float64), minlength=KEYS)
+    present = np.bincount(rows[:, IP], minlength=KEYS) > 0
+    return {int(k): int(sums[k]) for k in np.flatnonzero(present)}
+
+
+def _plan(rows: int, M: int, **kw) -> ExecutionPlan:
+    cfg = JobConfig(num_mappers=M, num_reducers=kw.pop("R", 5), **kw)
+    return ExecutionPlan(APP, cfg, rows * WIDTH)
+
+
+# rows not a multiple of M; M > rows / 2; splits whose tokens fill whole
+# vector registers (the lane-tiled split)
+SIZES = [(2003, 7), (2003, 20), (2003, 1500), (2000, 5)]
+
+
+def _run(plan, mode, table):
+    if mode == "fused":
+        return plan.fused()(table)
+    if mode == "pipelined":
+        return plan.pipelined(depth=2)(table)
+    if mode == "traced":
+        return plan.traced(PhaseRecorder())(table)
+    job = plan.resumable()
+    return job.result(job.run(table))
+
+
+@pytest.mark.parametrize("mode", ["fused", "pipelined", "traced",
+                                  "resumable"])
+@pytest.mark.parametrize("rows,M", SIZES)
+def test_every_mode_gives_the_group_by_sum(rows, M, mode):
+    table = _table(rows)
+    ok, ov, dropped = _run(_plan(rows, M, num_workers=3), mode, table)
+    assert int(dropped) == 0
+    assert collect_results(ok, ov) == _group_by_sum(table)
+
+
+@pytest.mark.parametrize("rows,M", SIZES)
+def test_splits_are_whole_records(rows, M):
+    plan = _plan(rows, M)
+    S = math.ceil(rows / M)
+    assert plan.rows == rows and plan.S == S and plan.P == S
+    splits, valid = plan.prep()(_table(rows))
+    assert splits.shape[0] == M and splits.size == M * S * WIDTH
+    assert valid.shape == (M, S) and int(valid.sum()) == rows
+    assert np.asarray(valid).ravel()[:rows].all()
+    # a lane-tiled split where the split's tokens fill whole registers
+    tiled = S * WIDTH % 128 == 0
+    assert splits.shape[1:] == ((S * WIDTH // 128, 128) if tiled
+                                else (S * WIDTH,))
+    m = plan.meta()
+    assert (m["records"], m["record_width"], m["split_size"],
+            m["n_pairs"]) == (rows, WIDTH, S, M * S)
+
+
+@pytest.mark.parametrize("mode", ["fused", "pipelined"])
+@pytest.mark.parametrize("rows,M", SIZES)
+def test_map_counts_records_and_slots(rows, M, mode):
+    plan = _plan(rows, M)
+    table = _table(rows)
+    job = plan.fused(counters=True) if mode == "fused" else \
+        plan.pipelined(depth=2, counters=True)
+    *_, stats = job(table)
+    c = {k: int(v) for k, v in stats.items()}
+    assert c["map.slots"] == M * math.ceil(rows / M)
+    assert c["map.records"] == rows == c["map.pairs"]
+    assert c["shuffle.pairs"] == rows and c["shuffle.dropped"] == 0
+
+
+def test_traced_map_records_records_and_slots():
+    rows, M = 2003, 7
+    recorder = PhaseRecorder()
+    _plan(rows, M).traced(recorder)(_table(rows))
+    t = recorder.last
+    assert t.counter("map", "records_in") == rows
+    assert t.counter("map", "pairs_capacity") == M * math.ceil(rows / M)
+    assert t.counter("map", "pairs_emitted") == rows
+    assert t.check_conservation() == []
+
+
+def test_resumable_regrants_mid_map():
+    rows, M = 2003, 7
+    table = _table(rows)
+    job = _plan(rows, M, num_workers=2).resumable()
+    state = job.run(table, preempt_after=2)
+    state = job.regrant(state, 3)
+    ok, ov, dropped = job.result(job.run(table, state=state))
+    assert int(dropped) == 0
+    assert collect_results(ok, ov) == _group_by_sum(table)
+
+
+def test_input_must_be_whole_records():
+    with pytest.raises(ValueError, match="whole records"):
+        ExecutionPlan(APP, JobConfig(num_mappers=2, num_reducers=2),
+                      10 * WIDTH + 3)
+
+
+@pytest.mark.parametrize("col", [-1, WIDTH])
+def test_columns_lie_inside_a_row(col):
+    with pytest.raises(ValueError, match="outside a row"):
+        uservisits(KEYS, WIDTH, col, REVENUE)
+
+
+def test_width_one_is_token_granular():
+    """A width-1 app splits its tokens as before: M splits of
+    ``ceil(n / M)`` tokens, one pair slot each, a 2-D split."""
+    corpus = wordcount_corpus(1001, vocab_size=37, seed=2)
+    cfg = JobConfig(num_mappers=7, num_reducers=3, num_workers=2)
+    plan = ExecutionPlan(wordcount(37), cfg, len(corpus))
+    S = math.ceil(len(corpus) / 7)
+    assert plan.meta() == {
+        "input_len": 1001, "record_width": 1, "records": 1001,
+        "mappers": 7, "reducers": 3, "workers": 2, "split_size": S,
+        "map_waves": 4, "reduce_waves": 2, "n_pairs": 7 * S,
+        "combiner": False, "combine_capacity": 37, "shuffle_width": S,
+        "partition_capacity": min(math.ceil(7 * S / 3 * 4.0), 7 * S),
+        "r_pad": 3, "overlap_depth": 1,
+    }
+    splits, valid = plan.prep()(corpus)
+    assert splits.shape == valid.shape == (7, S)
+    ok, ov, dropped, stats = plan.fused(counters=True)(corpus)
+    assert int(dropped) == 0
+    assert collect_results(ok, ov) == dict(Counter(corpus.tolist()))
+    assert int(stats["map.records"]) == 1001
+    assert int(stats["map.slots"]) == 7 * S
+
+
+_SHARDED = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+import jax
+import numpy as np
+sys.path.insert(0, {tests!r})
+from test_records import APP, WIDTH, _group_by_sum, _table
+from repro.mapreduce import ExecutionPlan, JobConfig, collect_results
+
+for W, rows, M in [(1, 2003, 7), (2, 2003, 9), (4, 2003, 6), (4, 2000, 8),
+                   (4, 101, 60)]:
+    mesh = jax.make_mesh((W,), ("workers",), devices=jax.devices()[:W])
+    cfg = JobConfig(num_mappers=M, num_reducers=5, num_workers=W,
+                    capacity_factor=8.0, shuffle_backend="all_to_all")
+    plan = ExecutionPlan(APP, cfg, rows * WIDTH)
+    table = _table(rows)
+    ok, ov, dropped, stats = plan.sharded(mesh, counters=True)(table)
+    assert int(dropped) == 0, (W, rows, M)
+    assert collect_results(ok, ov) == _group_by_sum(table), (W, rows, M)
+    # the mesh maps whole waves: ceil(M / W) * W splits
+    M_pad = -(-M // W) * W
+    assert int(stats["map.slots"]) == M_pad * plan.S, (W, rows, M)
+    assert int(stats["map.records"]) == rows == int(stats["map.pairs"])
+print("OK")
+"""
+
+
+def test_sharded_gives_the_group_by_sum_on_four_devices():
+    env = {"PYTHONPATH": str(ROOT / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": "/usr/bin:/bin"}
+    script = _SHARDED.replace("{tests!r}", repr(str(ROOT / "tests")))
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-3000:]
