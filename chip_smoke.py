@@ -10,8 +10,9 @@ One chip runs, through the normal entry points (``build_job``,
   stream) at M=20, R=5: fused with the ``jnp`` and ``xla`` reduce
   backends, each with the combiner off and on; pipelined at overlap depth
   2; traced once (per-phase walls).  Reference: ``np.bincount``.
-* ``exim`` — Exim mainlog parsing over 2^26 tokens, ``xla`` backend.
-  Reference: the fixed-width records parsed split by split in numpy.
+* ``exim`` — Exim mainlog parsing over the whole records of 2^26 tokens
+  (2^26 - 1 tokens), ``xla`` backend.  Reference: the sizes summed per
+  transaction id over every record in numpy.
 * ``pallas`` — the Pallas reduce backend, compiled (the HLO must hold a
   ``tpu_custom_call``), with and without the combiner, on a job whose
   partitions fit the kernels' ``MAX_C``; bit-exact against ``jnp``.
@@ -51,6 +52,7 @@ WC_TOKENS = 1 << 26
 WC_VOCAB = 4096
 WC_M, WC_R = 20, 5
 EXIM_TXNS = 1024
+EXIM_TOKENS = WC_TOKENS - WC_TOKENS % 3  # whole [txn, event, size] records
 PALLAS_TOKENS = 1 << 12     # M=R=8: partitions exactly MAX_C wide
 PALLAS_M = PALLAS_R = 8
 LOOP_TOKENS = 1 << 24
@@ -115,27 +117,15 @@ def counts_match(ok, ov, want: dict) -> bool:
     return collect_results(ok, ov) == want
 
 
-def exim_reference(log, M: int, n_txn: int) -> dict:
-    """Sum of sizes per transaction id, parsed as ``_eximparse_map`` does:
-    the stream is cut into M splits of S tokens (zero-padded), and each
-    split holds S // 3 whole [txn, event, size] records; a record with a
-    token past the end of the log is invalid."""
+def exim_reference(log, n_txn: int) -> dict:
+    """Sum of sizes per transaction id over every ``[txn, event, size]``
+    record of the log."""
     import numpy as np
 
     from repro.mapreduce.apps import RECORD_WIDTH
 
-    n = len(log)
-    S = -(-n // M)
-    n_rec = S // RECORD_WIDTH
-    idx = np.arange(M * S).reshape(M, S)[:, : n_rec * RECORD_WIDTH]
-    rec = np.zeros(M * S, np.int64)
-    rec[:n] = log
-    rec = rec[idx].reshape(M, n_rec, RECORD_WIDTH)
-    valid = (idx.reshape(M, n_rec, RECORD_WIDTH) < n).all(axis=2)
-    # A split whose start is not a multiple of RECORD_WIDTH reads shifted
-    # records, so keys may exceed n_txn and a key's sum may be 0: key
-    # presence comes from a count, not from a nonzero sum.
-    keys, sizes = rec[..., 0][valid], rec[..., 2][valid]
+    rec = log.reshape(-1, RECORD_WIDTH)
+    keys, sizes = rec[:, 0], rec[:, 2]
     present = np.flatnonzero(np.bincount(keys, minlength=n_txn))
     sums = np.bincount(keys, weights=sizes, minlength=n_txn)
     return {int(k): int(sums[k]) for k in present}
@@ -230,7 +220,7 @@ def run_exim(smoke: Smoke, n: int, future):
 
     t0 = time.perf_counter()
     log = exim_mainlog(n, n_transactions=EXIM_TXNS, seed=SEED)
-    want = exim_reference(log, WC_M, EXIM_TXNS)
+    want = exim_reference(log, EXIM_TXNS)
     tok = jax.device_put(log)
     print(f"phase=exim setup tokens={n} M={WC_M} R={WC_R} "
           f"datagen_s={time.perf_counter() - t0:.3f}", flush=True)
@@ -424,7 +414,7 @@ def main(argv=None) -> int:
         if args.chips == 4:
             smoke.guarded("sharded", four_chips, smoke, WC_TOKENS)
         else:
-            one_chip(smoke, {"wordcount": WC_TOKENS, "exim": WC_TOKENS,
+            one_chip(smoke, {"wordcount": WC_TOKENS, "exim": EXIM_TOKENS,
                              "pallas": PALLAS_TOKENS, "loop": LOOP_TOKENS})
     finally:
         smoke.pool.shutdown(wait=True, cancel_futures=True)

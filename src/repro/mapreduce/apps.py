@@ -6,9 +6,9 @@ aggregation, on the TPU MapReduce engine.
 * **Exim Mainlog parsing** — Exim logs are sequences of per-message records;
   the Hadoop job groups log lines by transaction id.  Our token encoding of a
   mainlog is a flat stream of fixed-width records
-  ``[txn_id, event_type, size]``; map emits ``<txn_id, packed(event, size)>``
-  and reducers aggregate per transaction (event count + total bytes packed in
-  one int32).  (Paper §V.A, ref [35].)
+  ``[txn_id, event_type, size]``: the app declares that width, so the plan
+  splits the log on records and the map emits one ``<txn_id, size>`` pair per
+  record; reducers sum the bytes per transaction.  (Paper §V.A, ref [35].)
 * **UserVisits aggregation** — ``SELECT sourceIP, SUM(adRevenue) FROM
   UserVisits GROUP BY sourceIP`` (Pavlo et al., SIGMOD 2009, §4.3; HiBench
   ``sql/aggregation``) over a table of fixed-width int32 rows: the app
@@ -25,6 +25,37 @@ import jax.numpy as jnp
 
 from repro.mapreduce.engine import MapReduceApp, PAD_KEY
 from repro.mapreduce.phases import LANES
+
+# ---------------------------------------------------------------------------
+# Fixed-width records
+# ---------------------------------------------------------------------------
+
+
+def _column(tokens, width: int, col: int):
+    """Column ``col`` of a split of ``width``-token rows, read from a
+    lane-dense ``(rows * width / LANES, LANES)`` view (row ``i * k + j`` of
+    ``k = LANES / width`` rows at lane ``j * width + col``) where the rows
+    tile the lanes, else from the flat split by stride; a ``(rows, width)``
+    view would pad each row out to a whole vector register."""
+    if LANES % width == 0 and tokens.size % LANES == 0:
+        return tokens.reshape(-1, LANES)[:, col::width].reshape(-1)
+    return tokens.reshape(-1)[col::width]
+
+
+def _record_pairs(width: int, key_col: int, value_col: int):
+    """The map of one ``<record[key_col], record[value_col]>`` pair per
+    record of ``width`` tokens: the plan hands it a split of S whole
+    records and one validity flag per record, and it emits S pairs."""
+
+    def map_fn(tokens, valid):
+        key = _column(tokens, width, key_col)
+        value = _column(tokens, width, value_col)
+        keys = jnp.where(valid, key, PAD_KEY)
+        values = jnp.where(valid, value, 0).astype(jnp.int32)
+        return keys, values, valid
+
+    return map_fn
+
 
 # ---------------------------------------------------------------------------
 # WordCount
@@ -54,39 +85,16 @@ def wordcount(vocab_size: int = 4096) -> MapReduceApp:
 RECORD_WIDTH = 3  # [txn_id, event_type, size_bytes]
 
 
-def _eximparse_map(tokens, valid):
-    """Parse fixed-width records from a split; emit <txn_id, size>.
-
-    A split of S tokens holds S // RECORD_WIDTH whole records; trailing
-    partial records are invalid (in real Hadoop, input splits are
-    line-aligned; our fixed-width records make alignment static).  Reducers
-    sum sizes per transaction id — the per-transaction grouping/aggregation
-    of the paper's Exim job.
-    """
-    S = tokens.shape[0]
-    n_rec = S // RECORD_WIDTH
-    rec = tokens[: n_rec * RECORD_WIDTH].reshape(n_rec, RECORD_WIDTH)
-    rec_valid = valid[: n_rec * RECORD_WIDTH].reshape(n_rec, RECORD_WIDTH).all(
-        axis=1
-    )
-    txn = rec[:, 0]
-    size = rec[:, 2]
-    keys = jnp.where(rec_valid, txn, PAD_KEY)
-    values = jnp.where(rec_valid, size, 0).astype(jnp.int32)
-    # Static output size: one pair per record slot; pad to S with invalid
-    # pairs so every map task emits the same-shaped output.
-    pad = S - n_rec
-    keys = jnp.concatenate([keys, jnp.full((pad,), PAD_KEY, jnp.int32)])
-    values = jnp.concatenate([values, jnp.zeros((pad,), jnp.int32)])
-    pvalid = jnp.concatenate([rec_valid, jnp.zeros((pad,), bool)])
-    return keys, values, pvalid
-
-
 def eximparse(n_transactions: int = 1024) -> MapReduceApp:
+    """<record> -> <txn_id, size>, one pair per record; reducers sum the
+    sizes per transaction id, the per-transaction grouping of the paper's
+    Exim job.  The plan splits the log on records, as Hadoop's input
+    splits are line-aligned, so no record straddles two map tasks."""
     return MapReduceApp(
         name="eximparse",
         key_space=n_transactions,
-        map_fn=_eximparse_map,
+        map_fn=_record_pairs(RECORD_WIDTH, 0, 2),
+        record_width=RECORD_WIDTH,
         reduce_op="sum",
     )
 
@@ -94,28 +102,6 @@ def eximparse(n_transactions: int = 1024) -> MapReduceApp:
 # ---------------------------------------------------------------------------
 # UserVisits aggregation
 # ---------------------------------------------------------------------------
-
-def _column(tokens, width: int, col: int):
-    """Column ``col`` of a split of ``width``-token rows, read from a
-    lane-dense ``(rows * width / LANES, LANES)`` view (row ``i * k + j`` of
-    ``k = LANES / width`` rows at lane ``j * width + col``) where the rows
-    tile the lanes, else from the flat split by stride; a ``(rows, width)``
-    view would pad each row out to a whole vector register."""
-    if LANES % width == 0 and tokens.size % LANES == 0:
-        return tokens.reshape(-1, LANES)[:, col::width].reshape(-1)
-    return tokens.reshape(-1)[col::width]
-
-
-def _uservisits_map(width: int, ip_col: int, revenue_col: int):
-    def map_fn(tokens, valid):
-        """<row> -> <sourceIP, adRevenue>, one pair per row."""
-        ip = _column(tokens, width, ip_col)
-        revenue = _column(tokens, width, revenue_col)
-        keys = jnp.where(valid, ip, PAD_KEY)
-        values = jnp.where(valid, revenue, 0).astype(jnp.int32)
-        return keys, values, valid
-
-    return map_fn
 
 
 def uservisits(key_space: int, record_width: int, ip_col: int,
@@ -130,7 +116,7 @@ def uservisits(key_space: int, record_width: int, ip_col: int,
     return MapReduceApp(
         name="uservisits",
         key_space=key_space,
-        map_fn=_uservisits_map(record_width, ip_col, revenue_col),
+        map_fn=_record_pairs(record_width, ip_col, revenue_col),
         record_width=record_width,
         reduce_op="sum",
     )

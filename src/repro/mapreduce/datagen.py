@@ -28,7 +28,9 @@ def wordcount_corpus(
 def exim_mainlog(
     n_tokens: int, n_transactions: int = 1024, *, seed: int = 0
 ) -> np.ndarray:
-    """Flat [txn_id, event_type, size]* stream, truncated to n_tokens."""
+    """Flat [txn_id, event_type, size]* stream of whole records: the
+    ``n_tokens // 3`` records that fit in ``n_tokens`` tokens, so its
+    length is ``n_tokens`` rounded down to a multiple of 3."""
     rng = np.random.default_rng(seed)
     n_records = n_tokens // RECORD_WIDTH + 1
     # Each transaction produces a burst of 2-6 consecutive events
@@ -41,5 +43,5 @@ def exim_mainlog(
     txn = np.asarray(txn_ids[:n_records], dtype=np.int32)
     event = rng.integers(0, 8, size=n_records).astype(np.int32)
     size = rng.integers(200, 4000, size=n_records).astype(np.int32)
-    stream = np.stack([txn, event, size], axis=1).reshape(-1)[:n_tokens]
-    return stream.astype(np.int32)
+    stream = np.stack([txn, event, size], axis=1).reshape(-1)
+    return stream[: n_tokens - n_tokens % RECORD_WIDTH].astype(np.int32)

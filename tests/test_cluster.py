@@ -339,6 +339,15 @@ class TestEngineOracleSmoke:
         assert res.metrics()["n_completed"] == 2
         assert all(r.true_time > 0 for r in res.records)
 
+    def test_exim_job_at_a_size_that_is_not_whole_records(self):
+        # 32768 tokens = 3 * 10922 + 2: the log is the 10922 whole records
+        from repro.cluster import EngineOracle
+
+        oracle = EngineOracle()
+        assert oracle.time("eximparse", "xla", 32768, 4, 4, 2) > 0
+        _, log = oracle._corpus("eximparse", 32768)
+        assert len(log) == 32766
+
 
 # ---------------------------------------------------------------------------
 # Telemetry integration: traces, per-phase refits, resource-aware policy,

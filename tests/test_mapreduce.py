@@ -1,6 +1,5 @@
 """MapReduce engine correctness: wave scheduling, shuffle, reduce, apps."""
 
-import math
 from collections import Counter
 
 import numpy as np
@@ -18,16 +17,11 @@ from repro.mapreduce import (
 )
 
 
-def _exim_oracle(log: np.ndarray, M: int) -> dict:
-    """Total bytes per txn; records straddling split boundaries are dropped
-    (static split alignment, matching the engine)."""
-    S = math.ceil(len(log) / M)
+def _exim_oracle(log: np.ndarray) -> dict:
+    """Total bytes per txn over every ``[txn, event, size]`` record."""
     want: dict[int, int] = {}
-    for m in range(M):
-        split = log[m * S:(m + 1) * S]
-        for i in range(len(split) // 3):
-            t, _, s = split[3 * i:3 * i + 3]
-            want[int(t)] = want.get(int(t), 0) + int(s)
+    for t, _, s in log.reshape(-1, 3).tolist():
+        want[t] = want.get(t, 0) + s
     return want
 
 
@@ -80,14 +74,26 @@ class TestWordCount:
 
 
 class TestEximParse:
-    @pytest.mark.parametrize("M,R", [(6, 4), (2, 9)])
+    # 2000 records: M=6 and M=9 cut 6000 tokens into splits that would start
+    # inside a record if the log were split on tokens; M=7 leaves a
+    # ragged last split
+    @pytest.mark.parametrize("M,R", [(6, 4), (2, 9), (7, 5), (9, 3)])
     def test_per_transaction_bytes(self, M, R):
         log = exim_mainlog(6000, n_transactions=50, seed=3)
         app = eximparse(50)
         cfg = JobConfig(num_mappers=M, num_reducers=R, capacity_factor=8.0)
         ok, ov, dropped = build_job(app, cfg, len(log))(log)
         assert int(dropped) == 0
-        assert collect_results(ok, ov) == _exim_oracle(log, M)
+        assert collect_results(ok, ov) == _exim_oracle(log)
+
+    @pytest.mark.parametrize("n", [5999, 6000, 6001, 6002])
+    def test_mainlog_is_whole_records(self, n):
+        """The generator keeps the whole records that fit in ``n``
+        tokens."""
+        rec = exim_mainlog(n, n_transactions=50, seed=3).reshape(-1, 3)
+        assert len(rec) == n // 3
+        assert ((0 <= rec[:, 0]) & (rec[:, 0] < 50)).all()
+        assert ((200 <= rec[:, 2]) & (rec[:, 2] < 4000)).all()
 
 
 class TestWaveScheduling:

@@ -63,6 +63,9 @@ def _uservisits_table(rows: int = 90, seed: int = 7) -> np.ndarray:
 APPS = {
     "wordcount": (wordcount(53), CORPUS),
     "exim": (eximparse(1024), _exim_log()),
+    # 213 messages: 5 splits of 128 records, whose tokens fill whole
+    # vector registers (the lane-tiled split)
+    "exim-lane": (eximparse(1024), _exim_log(213)),
     "uservisits": (uservisits(64, 32, 0, 14), _uservisits_table()),
 }
 
@@ -93,7 +96,8 @@ def _job(plan, mode, **kw):
 
 @pytest.mark.parametrize("mode", ["fused", "pipelined"])
 @pytest.mark.parametrize("combiner", [False, True])
-@pytest.mark.parametrize("app_name", ["wordcount", "exim", "uservisits"])
+@pytest.mark.parametrize("app_name", ["wordcount", "exim", "uservisits",
+                                      "exim-lane"])
 def test_scope_map_covers_the_compiled_job(app_name, combiner, mode):
     # M=5 over W=1 keeps pipelined(depth=2)'s last wave group ragged.
     plan, corpus = _plan(app_name, combiner=combiner)
@@ -157,6 +161,7 @@ CASES = {
     "exim": dict(app_name="exim"),
     "exim-a2a-combine": dict(app_name="exim", combiner=True, num_workers=2,
                              shuffle_backend="all_to_all"),
+    "exim-lane": dict(app_name="exim-lane"),
     "uservisits": dict(app_name="uservisits"),
     "overflow": dict(app_name="wordcount", num_reducers=4,
                      capacity_factor=1.0),
@@ -312,11 +317,11 @@ def test_map_live_pct_reads_the_map_counters():
 
     reader = harness.load_module(harness.BENCH / "metrics" /
                                  "map_live_pct.py")
+    # one pair slot per Exim record, and 5 splits of 24 records: all live
     plan, corpus = _plan("exim")
     *_, stats = plan.fused(counters=True)(corpus)
     counters = {k: int(v) for k, v in stats.items()}
-    assert reader.read(SimpleNamespace(counters=counters)) == \
-        pytest.approx(100 / 3, rel=0.05)
+    assert reader.read(SimpleNamespace(counters=counters)) == 100.0
     assert reader.read(SimpleNamespace(counters={
         "map.pairs": 5, "map.slots": 20})) == 25.0
     # a program that counts no map slots, and a run without counters

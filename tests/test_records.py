@@ -2,9 +2,10 @@
 split on record boundaries, with any row count and any M, and its map emits
 ``pairs_per_record`` pair slots per record slot, in every mode.
 
-The app under test is the UserVisits aggregation (``SELECT sourceIP,
+The apps under test are the UserVisits aggregation (``SELECT sourceIP,
 SUM(adRevenue) GROUP BY sourceIP``) over seeded tables of 32-token rows,
-checked against a numpy GROUP BY SUM.
+checked against a numpy GROUP BY SUM, and Exim mainlog parsing over
+3-token records, checked against the sizes summed per transaction id.
 """
 
 import math
@@ -20,6 +21,8 @@ from repro.mapreduce import (
     ExecutionPlan,
     JobConfig,
     collect_results,
+    exim_mainlog,
+    eximparse,
     uservisits,
     wordcount,
     wordcount_corpus,
@@ -135,9 +138,12 @@ def test_resumable_regrants_mid_map():
 
 
 def test_input_must_be_whole_records():
+    cfg = JobConfig(num_mappers=2, num_reducers=2)
     with pytest.raises(ValueError, match="whole records"):
-        ExecutionPlan(APP, JobConfig(num_mappers=2, num_reducers=2),
-                      10 * WIDTH + 3)
+        ExecutionPlan(APP, cfg, 10 * WIDTH + 3)
+    with pytest.raises(ValueError, match="3001 tokens is not whole records "
+                                         "of 3 tokens"):
+        ExecutionPlan(eximparse(50), cfg, 3001)
 
 
 @pytest.mark.parametrize("col", [-1, WIDTH])
@@ -170,6 +176,59 @@ def test_width_one_is_token_granular():
     assert int(stats["map.slots"]) == 7 * S
 
 
+EXIM = eximparse(1024)
+# (records, M): splits of 128 records, whose 384 tokens fill whole vector
+# registers (the lane-tiled split); 7 full flat splits of 286 records; a
+# ragged last split
+EXIM_SIZES = [(640, 5), (2002, 7), (2000, 7)]
+
+
+def _mainlog(records: int) -> np.ndarray:
+    return exim_mainlog(3 * records, n_transactions=1024, seed=records)
+
+
+def _per_txn(log: np.ndarray) -> dict[int, int]:
+    rec = log.reshape(-1, 3)
+    sums = np.bincount(rec[:, 0], weights=rec[:, 2], minlength=1024)
+    return {int(k): int(sums[k]) for k in np.unique(rec[:, 0])}
+
+
+def _exim_plan(records: int, M: int, **kw) -> ExecutionPlan:
+    cfg = JobConfig(num_mappers=M, num_reducers=5, **kw)
+    return ExecutionPlan(EXIM, cfg, 3 * records)
+
+
+@pytest.mark.parametrize("mode", ["fused", "pipelined", "traced",
+                                  "resumable"])
+@pytest.mark.parametrize("records,M", EXIM_SIZES)
+def test_exim_every_mode_gives_the_fused_output(records, M, mode):
+    log = _mainlog(records)
+    plan = _exim_plan(records, M, num_workers=3)
+    ok, ov, dropped = _run(plan, mode, log)
+    assert int(dropped) == 0
+    assert collect_results(ok, ov) == _per_txn(log)
+    want = plan.fused()(log)
+    assert np.array_equal(np.asarray(ok), np.asarray(want[0]))
+    assert np.array_equal(np.asarray(ov), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("records,M", EXIM_SIZES)
+def test_exim_emits_one_pair_slot_per_record(records, M):
+    plan = _exim_plan(records, M)
+    assert plan.P == math.ceil(records / M)
+    splits, valid = plan.prep()(_mainlog(records))
+    tiled = plan.P * 3 % 128 == 0
+    assert splits.shape == ((M, plan.P * 3 // 128, 128) if tiled
+                            else (M, plan.P * 3))
+    assert valid.shape == (M, plan.P)
+    *_, stats = plan.fused(counters=True)(_mainlog(records))
+    c = {k: int(v) for k, v in stats.items()}
+    assert c["map.slots"] == M * plan.P == c["shuffle.slots"]
+    assert c["map.records"] == records == c["map.pairs"]
+    if records % M == 0:
+        assert c["map.pairs"] == c["map.slots"]
+
+
 _SHARDED = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -177,7 +236,8 @@ import sys
 import jax
 import numpy as np
 sys.path.insert(0, {tests!r})
-from test_records import APP, WIDTH, _group_by_sum, _table
+from test_records import APP, WIDTH, EXIM, _group_by_sum, _mainlog, \
+    _per_txn, _table
 from repro.mapreduce import ExecutionPlan, JobConfig, collect_results
 
 for W, rows, M in [(1, 2003, 7), (2, 2003, 9), (4, 2003, 6), (4, 2000, 8),
@@ -194,6 +254,20 @@ for W, rows, M in [(1, 2003, 7), (2, 2003, 9), (4, 2003, 6), (4, 2000, 8),
     M_pad = -(-M // W) * W
     assert int(stats["map.slots"]) == M_pad * plan.S, (W, rows, M)
     assert int(stats["map.records"]) == rows == int(stats["map.pairs"])
+# Exim's 3-token records, lane-tiled and flat: the mesh job's output is the
+# emulated collective's, bit for bit
+for W, records, M in [(4, 640, 5), (2, 2002, 7), (4, 2000, 7)]:
+    mesh = jax.make_mesh((W,), ("workers",), devices=jax.devices()[:W])
+    cfg = JobConfig(num_mappers=M, num_reducers=5, num_workers=W,
+                    capacity_factor=8.0, shuffle_backend="all_to_all")
+    plan = ExecutionPlan(EXIM, cfg, 3 * records)
+    log = _mainlog(records)
+    ok, ov, dropped = plan.sharded(mesh)(log)
+    assert int(dropped) == 0, (W, records, M)
+    assert collect_results(ok, ov) == _per_txn(log), (W, records, M)
+    fk, fv, _ = plan.fused()(log)
+    assert np.array_equal(np.asarray(ok), np.asarray(fk)), (W, records, M)
+    assert np.array_equal(np.asarray(ov), np.asarray(fv)), (W, records, M)
 print("OK")
 """
 
