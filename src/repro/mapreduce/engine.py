@@ -172,8 +172,9 @@ def build_job_sharded(
 ):
     """shard_map MapReduce: W = mesh axis size; shuffle = all_to_all.
 
-    A thin wrapper over :meth:`ExecutionPlan.sharded` — the same wave
-    steppers as every other mode, wrapped in ``shard_map``.  This is the
+    A thin wrapper over :meth:`ExecutionPlan.sharded` — ``shard_map``
+    over the plan's own map and reduce waves, each worker running its own
+    task rows and partitions through them one at a time.  This is the
     deployment path for real multi-chip meshes; semantics match
     :func:`build_job`.
 
@@ -189,7 +190,8 @@ def build_job_sharded(
 
     With ``recorder=`` the phases compile as separate mesh programs and
     every call appends a per-phase :class:`~repro.telemetry.JobTrace` —
-    per-phase *wall times* on the sharded path.
+    per-phase *wall times* on the sharded path, recorded by the same
+    fenced runner as the traced mode's.
     """
     plan = ExecutionPlan(app, cfg, input_len)
     return plan.sharded(mesh, axis, counters=counters, recorder=recorder)

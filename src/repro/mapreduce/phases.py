@@ -1,22 +1,19 @@
 """Shared MapReduce phase primitives (the single source of truth).
 
-The engine used to carry two near-identical copies of the map-task,
-combiner, partition, and reduce logic — one in ``build_job`` and one in
-``build_job_sharded``.  This module is the one implementation both paths
-(and any future backend) compose:
+The per-task and per-partition work that every execution mode of
+:class:`repro.mapreduce.plan.ExecutionPlan` composes; the plan owns the
+wave scheduling around it (one map wave and one reduce wave for every
+mode):
 
 * :func:`task_setup`        — fixed per-task startup compute (JVM analogue);
 * :func:`hash_to_reducer`   — Knuth multiplicative key hashing;
 * :func:`segment_sum_sorted`— sorted equal-key aggregation (sum / max / first);
 * :func:`run_map_task`      — setup + ``map_fn`` + local spill sort;
-* :func:`map_phase`         — wave-scheduled map over (waves, W) task grid;
 * :func:`combine_rows`      — map-side combine: per-task aggregation +
   compaction of the spill-sorted rows, shrinking everything downstream
   (:func:`combine_capacity` is the static distinct-key bound);
 * :func:`bucket_scatter`    — capacity-bounded partition scatter, with
   overflow *accounting* (the ``dropped`` count) instead of silent loss;
-* :func:`reduce_phase` / :func:`reduce_local` — wave-scheduled reduce
-  through a pluggable :class:`repro.mapreduce.backends.ReduceBackend`;
 * :func:`scoped`            — a phase function under its named scope
   ``mr.<phase>``, so a profile of any program built from it can say which
   phase each device op belongs to;
@@ -273,27 +270,6 @@ def run_map_task(app, cfg, tokens, valid):
     return keys, values, pvalid
 
 
-def map_phase(app, cfg, splits, split_valid):
-    """Run map tasks in waves of W workers.
-
-    splits: (waves, W, <one split>) int32; split_valid: (waves, W, S)
-    bool.
-    Returns keys/values/valid of shape (waves, W, P).
-    """
-
-    def wave(carry, inp):
-        tok, val = inp
-        k, v, pv = jax.vmap(lambda t, m: run_map_task(app, cfg, t, m))(
-            tok, val
-        )
-        return carry, (k, v, pv)
-
-    _, (keys, values, pvalid) = jax.lax.scan(
-        wave, jnp.int32(0), (splits, split_valid)
-    )
-    return keys, values, pvalid
-
-
 def partition_capacity(n_pairs: int, n_buckets: int, factor: float) -> int:
     """Capacity per partition: uniform share x safety factor, clamped."""
     cap = max(1, int(math.ceil(n_pairs / max(n_buckets, 1) * factor)))
@@ -369,44 +345,3 @@ def _masked_setup(cfg, keys_block, out_keys, out_vals):
     return out_vals + jnp.where(live, setup[:, None], 0.0).astype(
         out_vals.dtype
     )
-
-
-def reduce_phase(app, cfg, part_keys, part_vals, backend):
-    """Wave-scheduled reduce: R tasks in ``reduce_waves`` waves of W workers.
-
-    part_keys/part_vals: (R_pad, cap) with R_pad = reduce_waves * W, each row
-    sorted by key with PAD_KEY padding.  The per-partition aggregation is
-    delegated to ``backend`` (a :class:`~repro.mapreduce.backends.ReduceBackend`).
-    Returns out_keys/out_vals of shape (R_pad, cap).
-    """
-    waves_r, W = cfg.reduce_waves, cfg.num_workers
-    cap = part_keys.shape[1]
-    pk = part_keys.reshape(waves_r, W, cap)
-    pv = part_vals.reshape(waves_r, W, cap)
-
-    def wave(carry, inp):
-        k, v = inp  # (W, cap): one wave of W reduce tasks
-        ok, ov = backend.reduce(k, v, app.reduce_op)
-        ov = _masked_setup(cfg, k, ok, ov)
-        return carry, (ok, ov)
-
-    _, (ok, ov) = jax.lax.scan(wave, jnp.int32(0), (pk, pv))
-    return ok.reshape(waves_r * W, cap), ov.reshape(waves_r * W, cap)
-
-
-def reduce_local(app, cfg, part_keys, part_vals, backend):
-    """Per-worker serial reduce over this worker's owned reduce slots.
-
-    part_keys/part_vals: (slots, cap).  Each slot is one reduce task; they
-    run serially (a worker processes its waves one at a time), matching the
-    wave-scheduling semantics of the sharded path.
-    """
-
-    def one(carry, inp):
-        k, v = inp  # (cap,)
-        ok, ov = backend.reduce(k[None], v[None], app.reduce_op)
-        ov = _masked_setup(cfg, k[None], ok, ov)
-        return carry, (ok[0], ov[0])
-
-    _, (ok, ov) = jax.lax.scan(one, jnp.int32(0), (part_keys, part_vals))
-    return ok, ov

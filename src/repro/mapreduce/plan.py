@@ -1,15 +1,11 @@
 """One wave-stepper execution core: every execution mode is a derivation.
 
-The engine used to carry four hand-rolled lowerings of the same
-map → shuffle → reduce pipeline: the fused ``build_job`` composition, the
-recorder-fenced traced path, the ``shard_map``-fused sharded path, and
-``ResumableJob``'s per-grant wave steppers.  Each could silently drift
-from the others — and every drifted path is a profiled path whose time
-the paper's models would mis-attribute.
-
 :class:`ExecutionPlan` lowers one ``(MapReduceApp, JobConfig,
 input_len)`` into a single canonical stepper set over **task-major
-buffers**, and every entry point is a *mode* over that one plan:
+buffers**, and every entry point is a *mode* over that one plan.  Every
+mode maps through the same map wave and reduces through the same reduce
+wave (a compute/commit pair sliding a window of task rows), so no mode
+is a second lowering whose time the paper's models would mis-attribute:
 
 * :meth:`fused`     — ``fori_loop`` over the steppers under one ``jit``:
   the zero-overhead hot path (``build_job``'s default);
@@ -19,10 +15,11 @@ buffers**, and every entry point is a *mode* over that one plan:
   software-pipelined at ``cfg.overlap_depth``: wave group g's compute
   overlaps group g-1's commit in one loop carry (prologue / steady
   state / epilogue), bit-exact vs fused by construction;
-* :meth:`sharded`   — ``shard_map`` around the same phase primitives
-  (workers = mesh axis, shuffle = literal ``all_to_all``); with a
-  recorder the phases compile as *separate* mesh programs, which is what
-  finally makes per-phase wall times possible on the sharded path;
+* :meth:`sharded`   — ``shard_map`` over the same waves (workers = mesh
+  axis, each running its own task rows and partitions one at a time;
+  shuffle = literal ``all_to_all``); with a recorder the phases compile
+  as *separate* mesh programs, run and recorded by the same fenced
+  runner as :meth:`traced`;
 * :meth:`resumable` — the raw steppers jitted per grant for
   :class:`repro.elastic.resumable.ResumableJob`'s wave-boundary
   stop/snapshot/regrant/resume loop.
@@ -33,7 +30,8 @@ The canonical stepper contract (all shapes static per plan):
   the record-aligned split, ``S = ceil(rows / M)`` whole records of
   ``w = app.record_width`` tokens per task, one validity flag per record;
 * ``map_step(W)(splits, valid, bk, bv, bp, start)``
-                                          → updated ``(M, P)`` accumulators
+                                          → updated ``(M, P)`` accumulators:
+  commit of compute (:meth:`ExecutionPlan._map_wave`) at ``start``;
 * ``combine_step()(bk, bv, bp)``          → compacted ``(M, Pc)`` task rows
   (only when ``cfg.combiner``): per-task local segment-reduce +
   front-packing through the reduce backend's ``combine``, with
@@ -45,7 +43,8 @@ The canonical stepper contract (all shapes static per plan):
   the ``all_to_all`` backend the capacity layout of a real W-device run
   (its pack/unpack halves vmapped over a worker axis, the collective
   replaced by the block transpose it implements);
-* ``reduce_step(W)(pk, pv, ok, ov, start)`` → updated ``(R, cap)`` outputs.
+* ``reduce_step(W)(pk, pv, ok, ov, start)`` → updated ``(R, cap)`` outputs,
+  likewise of :meth:`ExecutionPlan._reduce_wave`.
 
 Every phase function runs under the named scope ``mr.<phase>``
 (:func:`repro.mapreduce.phases.scoped`), in every mode, so a profile of
@@ -76,8 +75,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.mapreduce import backends as _backends
 from repro.mapreduce import phases
-from repro.mapreduce.phases import PAD_KEY, map_phase, reduce_local, \
-    run_map_task
+from repro.mapreduce.phases import PAD_KEY, run_map_task
 
 __all__ = ["ExecutionPlan"]
 
@@ -88,12 +86,74 @@ __all__ = ["ExecutionPlan"]
 _NCPU = float(_os.cpu_count() or 1)
 
 
+#: fill of each buffer a map wave writes (keys, values, pair validity) and
+#: of each a reduce wave writes (keys, values)
+_MAP_FILLS = (PAD_KEY, 0, False)
+_REDUCE_FILLS = (PAD_KEY, 0)
+
+
 def _pad_rows(arr, n_extra: int, fill):
     """Append ``n_extra`` fill-rows so dynamic W-row windows never clamp."""
     if n_extra == 0:
         return arr
     pad = jnp.full((n_extra,) + arr.shape[1:], fill, dtype=arr.dtype)
     return jnp.concatenate([arr, pad], axis=0)
+
+
+# A wave's window: W task rows from ``start`` of arrays of ``rows`` rows.
+# Padding is only needed when the grant exceeds the task count (slice size
+# must fit the array).  For a final *partial* wave, XLA clamps the dynamic
+# start so the W-row window shifts onto already-processed rows — which
+# recompute bit-identically (map tasks are deterministic, reduce backends
+# row-independent by contract), so the in-place window needs no per-wave
+# pad/copy of the carried buffers.
+
+
+def _take(arrays, fills, start, W: int, rows: int):
+    """The window's rows of each of ``arrays``."""
+    pad = max(0, W - rows)
+    return tuple(
+        jax.lax.dynamic_slice_in_dim(_pad_rows(a, pad, f), start, W, 0)
+        for a, f in zip(arrays, fills)
+    )
+
+
+def _put(bufs, blocks, fills, start, W: int, rows: int):
+    """``bufs`` with the window's rows replaced by ``blocks``."""
+    pad = max(0, W - rows)
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(
+            _pad_rows(buf, pad, f), blk, start, 0
+        )[:rows]
+        for buf, blk, f in zip(bufs, blocks, fills)
+    )
+
+
+def _buffers(fills, rows: int, width: int):
+    """Fresh ``(rows, width)`` buffers, one per fill (int32 keys and
+    values, bool validity)."""
+    return tuple(
+        jnp.full((rows, width), f, bool if f is False else jnp.int32)
+        for f in fills
+    )
+
+
+def _step(compute, commit):
+    """The serial wave step of a compute/commit pair: commit of compute at
+    the same start, ``step(x, y, *bufs, start) -> bufs``."""
+
+    def step(x, y, *bufs_start):
+        *bufs, start = bufs_start
+        return commit(tuple(bufs), compute(x, y, start), start)
+
+    return step
+
+
+def _reducer_major(x, R: int):
+    """``(W, waves_r, cap)`` worker-major reduce slots -> ``(R, cap)``
+    indexed by reducer id: reducer r lives on worker r % W at local slot
+    r // W, so row r of the slot-major stacking is reducer r's."""
+    return x.transpose(1, 0, 2).reshape(-1, x.shape[-1])[:R]
 
 
 def _phase_counts(records) -> dict:
@@ -316,49 +376,50 @@ class ExecutionPlan:
         return prep
 
     def initial_map_buffers(self):
-        M, P = self.M, self.P
-        return (
-            jnp.full((M, P), PAD_KEY, jnp.int32),
-            jnp.zeros((M, P), jnp.int32),
-            jnp.zeros((M, P), bool),
-        )
+        return _buffers(_MAP_FILLS, self.M, self.P)
 
     def initial_reduce_buffers(self, cap: int):
-        R = self.R
-        return (
-            jnp.full((R, cap), PAD_KEY, jnp.int32),
-            jnp.zeros((R, cap), jnp.int32),
-        )
+        return _buffers(_REDUCE_FILLS, self.R, cap)
 
-    def _map_step_fn(self, W: int):
-        # Padding is only needed when the grant exceeds the task count
-        # (slice size must fit the array).  For a final *partial* wave,
-        # XLA clamps the dynamic start so the W-row window shifts onto
-        # already-processed rows — which recompute bit-identically (map
-        # tasks are deterministic and row-independent), so the in-place
-        # window needs no per-wave pad/copy of the (M, P) carries.
-        app, cfg, M = self.app, self.cfg, self.M
-        pad = max(0, W - M)
+    # -------------------------------------------- the map and reduce waves
+    #
+    # Each wave is split at its data-dependency boundary: ``compute`` reads
+    # only the immutable inputs (splits / partitions) and produces a block
+    # of W task rows; ``commit`` writes the block into the carried
+    # buffers.  The serial step is commit of compute at the same start
+    # (:func:`_step`); the pipelined mode overlaps group g's compute with
+    # group g-1's commit.  Each wave slides over ``rows`` task rows: M
+    # (or R) for the plan's buffers, a worker's own waves_m (or waves_r)
+    # rows on a mesh — so every mode maps and reduces through the same
+    # wave code.
 
-        def step(splits, svalid, bk, bv, bp, start):
-            tok = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(splits, pad, 0), start, W, 0
-            )
-            val = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(svalid, pad, False), start, W, 0
-            )
-            k, v, pv = jax.vmap(
+    def _map_wave(self, W: int, rows: int):
+        app, cfg = self.app, self.cfg
+
+        def compute(splits, svalid, start):
+            tok, val = _take((splits, svalid), (0, False), start, W, rows)
+            return jax.vmap(
                 lambda t, m: run_map_task(app, cfg, t, m)
             )(tok, val)
 
-            def upd(buf, blk, fill):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    _pad_rows(buf, pad, fill), blk, start, 0
-                )[:M]
+        def commit(bufs, blk, start):
+            return _put(bufs, blk, _MAP_FILLS, start, W, rows)
 
-            return upd(bk, k, PAD_KEY), upd(bv, v, 0), upd(bp, pv, False)
+        return compute, commit
 
-        return step
+    def _reduce_wave(self, W: int, rows: int):
+        op, cfg = self.app.reduce_op, self.cfg
+        backend = self.reduce_backend
+
+        def compute(pk, pv, start):
+            kblk, vblk = _take((pk, pv), _REDUCE_FILLS, start, W, rows)
+            ok, ov = backend.reduce(kblk, vblk, op)
+            return ok, phases._masked_setup(cfg, kblk, ok, ov)
+
+        def commit(bufs, blk, start):
+            return _put(bufs, blk, _REDUCE_FILLS, start, W, rows)
+
+        return compute, commit
 
     def _combine_step_fn(self):
         """Map-side combine barrier: aggregate + compact every task row.
@@ -413,7 +474,6 @@ class ExecutionPlan:
         cfg_w = dataclasses.replace(self.cfg, num_workers=W)
         shuffle, M, R = self.shuffle, self.M, self.R
         waves_m = cfg_w.map_waves
-        waves_r = cfg_w.reduce_waves
         M_pad = waves_m * W
         init_out = self.initial_reduce_buffers
 
@@ -449,122 +509,26 @@ class ExecutionPlan:
                     k.reshape(-1), v.reshape(-1), r.reshape(-1),
                 )
             )(recv_k, recv_v, recv_r)
-            # (W, waves_r, cap) -> reducer-indexed (R, cap): reducer r
-            # lives on worker r % W at local slot r // W.
-            cap = bk2.shape[-1]
-            pk = bk2.transpose(1, 0, 2).reshape(waves_r * W, cap)[:R]
-            pv = bv2.transpose(1, 0, 2).reshape(waves_r * W, cap)[:R]
-            ok, ov = init_out(cap)
+            pk, pv = _reducer_major(bk2, R), _reducer_major(bv2, R)
+            ok, ov = init_out(pk.shape[1])
             return pk, pv, sdrop.sum() + rdrop.sum(), ok, ov
 
         return step
 
-    def _reduce_step_fn(self, W: int):
-        # Same clamped-window discipline as the map stepper: reduce
-        # backends are row-independent by contract, so the shifted final
-        # wave rewrites earlier rows with identical aggregates.
-        app, cfg, R = self.app, self.cfg, self.R
-        backend = self.reduce_backend
-        pad = max(0, W - R)
+    @staticmethod
+    def _serial_loop(compute, commit, waves: int, stride: int, init_bufs):
+        """``waves`` serial wave steps from row 0, ``stride`` rows apart,
+        under one ``fori_loop``: ``run(x, y) -> bufs`` from
+        ``init_bufs(x, y)``."""
+        step = _step(compute, commit)
 
-        def step(pk, pv, ok_buf, ov_buf, start):
-            kblk = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(pk, pad, PAD_KEY), start, W, 0
-            )
-            vblk = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(pv, pad, 0), start, W, 0
-            )
-            ok, ov = backend.reduce(kblk, vblk, app.reduce_op)
-            ov = phases._masked_setup(cfg, kblk, ok, ov)
+        def run(x, y):
+            def body(i, bufs):
+                return step(x, y, *bufs, i * stride)
 
-            def upd(buf, blk, fill):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    _pad_rows(buf, pad, fill), blk, start, 0
-                )[:R]
+            return jax.lax.fori_loop(0, waves, body, init_bufs(x, y))
 
-            return upd(ok_buf, ok, PAD_KEY), upd(ov_buf, ov, 0)
-
-        return step
-
-    # ------------------------------------- split compute/commit steppers
-    #
-    # The pipelined mode needs the wave step split at its data-dependency
-    # boundary: ``compute`` reads only the immutable inputs (splits /
-    # partitions) and produces a task block; ``commit`` writes the block
-    # into the carried accumulators.  Wave group g's compute therefore has
-    # no dependency on group g-1's commit, and the scheduler can overlap
-    # them inside one loop iteration.  compute∘commit at the same start is
-    # exactly the fused step — same slices, same clamping — so the split
-    # changes scheduling, never values.
-
-    def _map_compute_fn(self, Weff: int):
-        app, cfg, M = self.app, self.cfg, self.M
-        pad = max(0, Weff - M)
-
-        def compute(splits, svalid, start):
-            tok = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(splits, pad, 0), start, Weff, 0
-            )
-            val = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(svalid, pad, False), start, Weff, 0
-            )
-            return jax.vmap(
-                lambda t, m: run_map_task(app, cfg, t, m)
-            )(tok, val)
-
-        return compute
-
-    def _map_commit_fn(self, Weff: int):
-        M = self.M
-        pad = max(0, Weff - M)
-
-        def commit(bufs, blk, start):
-            bk, bv, bp = bufs
-            k, v, pv = blk
-
-            def upd(buf, b, fill):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    _pad_rows(buf, pad, fill), b, start, 0
-                )[:M]
-
-            return upd(bk, k, PAD_KEY), upd(bv, v, 0), upd(bp, pv, False)
-
-        return commit
-
-    def _reduce_compute_fn(self, Weff: int):
-        app, cfg = self.app, self.cfg
-        backend = self.reduce_backend
-        pad = max(0, Weff - self.R)
-
-        def compute(pk, pv, start):
-            kblk = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(pk, pad, PAD_KEY), start, Weff, 0
-            )
-            vblk = jax.lax.dynamic_slice_in_dim(
-                _pad_rows(pv, pad, 0), start, Weff, 0
-            )
-            ok, ov = backend.reduce(kblk, vblk, app.reduce_op)
-            ov = phases._masked_setup(cfg, kblk, ok, ov)
-            return ok, ov
-
-        return compute
-
-    def _reduce_commit_fn(self, Weff: int):
-        R = self.R
-        pad = max(0, Weff - R)
-
-        def commit(bufs, blk, start):
-            ok_buf, ov_buf = bufs
-            ok, ov = blk
-
-            def upd(buf, b, fill):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    _pad_rows(buf, pad, fill), b, start, 0
-                )[:R]
-
-            return upd(ok_buf, ok, PAD_KEY), upd(ov_buf, ov, 0)
-
-        return commit
+        return run
 
     @staticmethod
     def _software_pipeline(compute, commit, groups: int, stride: int,
@@ -578,16 +542,16 @@ class ExecutionPlan:
         serial loop, so outputs are bit-exact by construction.
         """
 
-        def run(*inputs):
-            blk = compute(*inputs, 0)
+        def run(x, y):
+            blk = compute(x, y, 0)
 
             def body(g, carry):
                 bufs, blk = carry
                 bufs = commit(bufs, blk, (g - 1) * stride)
-                return bufs, compute(*inputs, g * stride)
+                return bufs, compute(x, y, g * stride)
 
             bufs, blk = jax.lax.fori_loop(
-                1, groups, body, (init_bufs(), blk)
+                1, groups, body, (init_bufs(x, y), blk)
             )
             return commit(bufs, blk, (groups - 1) * stride)
 
@@ -595,51 +559,47 @@ class ExecutionPlan:
 
     def pipelined_phase_fns(self, workers: int | None = None,
                             depth: int | None = None) -> dict:
-        """The pipeline's phase functions with map and reduce waves
-        software-pipelined at overlap depth D: waves are grouped D at a
-        time into blocks of ``W*D`` tasks, and the steady-state loop
+        """The pipeline as pure phase functions (map, the combine when
+        ``cfg.combiner``, shuffle, reduce), each under its named scope
+        ``mr.<phase>``, with map and reduce waves grouped D at a time into
+        blocks of ``W*D`` tasks (overlap depth D, default
+        ``cfg.overlap_depth``).  At ``D = 1`` each wave loop is the serial
+        ``fori_loop`` of :meth:`phase_fns`; above it, the steady-state loop
         commits group g-1 while computing group g.  The shuffle is the
-        global barrier between the two pipelines and is byte-identical
-        to the serial mode's.  ``depth=1`` degenerates to
-        :meth:`phase_fns` (today's schedule).
+        global barrier between the two loops and is the same at every
+        depth.
         """
         W = self.cfg.num_workers if workers is None else int(workers)
         D = (getattr(self.cfg, "overlap_depth", 1)
              if depth is None else int(depth))
         if D < 1:
             raise ValueError(f"overlap depth must be >= 1, got {D}")
-        if D == 1:
-            return self.phase_fns(W)
-        Weff_m = min(W * D, self.M)
-        Weff_r = min(W * D, self.R)
-        groups_m = math.ceil(self.M / Weff_m)
-        groups_r = math.ceil(self.R / Weff_r)
+        M, R = self.M, self.R
+        Weff_m = min(W * D, M)
+        Weff_r = min(W * D, R)
+        loop = self._serial_loop if D == 1 else self._software_pipeline
         prep = self._prep_fn()
         shuffle_step = self._shuffle_step_fn(
             W if self.shuffle.collective else 1
         )
-        map_pipe = self._software_pipeline(
-            self._map_compute_fn(Weff_m), self._map_commit_fn(Weff_m),
-            groups_m, Weff_m, self.initial_map_buffers,
+        map_loop = loop(
+            *self._map_wave(Weff_m, M), math.ceil(M / Weff_m), Weff_m,
+            lambda splits, valid: self.initial_map_buffers(),
         )
-        red_compute = self._reduce_compute_fn(Weff_r)
-        red_commit = self._reduce_commit_fn(Weff_r)
-        groups_r_, Weff_r_ = groups_r, Weff_r
-        init_red = self.initial_reduce_buffers
+        reduce_loop = loop(
+            *self._reduce_wave(Weff_r, R), math.ceil(R / Weff_r), Weff_r,
+            lambda pk, pv: self.initial_reduce_buffers(pk.shape[1]),
+        )
 
         def phase_map(tokens):
-            return map_pipe(*prep(tokens))
+            return map_loop(*prep(tokens))
 
         def phase_shuffle(bk, bv, bp):
             pk, pv, dropped, _, _ = shuffle_step(bk, bv, bp)
             return pk, pv, dropped
 
         def phase_reduce(pk, pv):
-            pipe = self._software_pipeline(
-                red_compute, red_commit, groups_r_, Weff_r_,
-                lambda: init_red(pk.shape[1]),
-            )
-            return pipe(pk, pv)
+            return reduce_loop(pk, pv)
 
         fns = {"map": phase_map}
         if self.combiner:
@@ -665,7 +625,9 @@ class ExecutionPlan:
         key = (min(int(W), self.M), self.combiner)
         if key not in self._jit_map:
             self._cache_misses += 1
-            self._jit_map[key] = jax.jit(self._map_step_fn(key[0]))
+            self._jit_map[key] = jax.jit(
+                _step(*self._map_wave(key[0], self.M))
+            )
         else:
             self._cache_hits += 1
         return self._jit_map[key]
@@ -692,7 +654,9 @@ class ExecutionPlan:
         key = (min(int(W), self.R), cap, self.combiner)
         if key not in self._jit_reduce:
             self._cache_misses += 1
-            self._jit_reduce[key] = jax.jit(self._reduce_step_fn(key[0]))
+            self._jit_reduce[key] = jax.jit(
+                _step(*self._reduce_wave(key[0], self.R))
+            )
         else:
             self._cache_hits += 1
         return self._jit_reduce[key]
@@ -713,51 +677,13 @@ class ExecutionPlan:
     # ------------------------------------------------- phase compositions
 
     def phase_fns(self, workers: int | None = None) -> dict:
-        """The pipeline as pure phase functions (map, the combine when
-        ``cfg.combiner``, shuffle, reduce) — each a stepper loop
-        (``fori_loop`` over waves) at one grant, under its named scope
-        ``mr.<phase>``.  Shared by the fused mode (composed under one
-        jit), the traced mode (jitted and fenced per phase), and the XLA
-        cost estimator (lowered per phase for abstract inputs).
+        """The pipeline as pure phase functions at one grant, each wave
+        loop a serial ``fori_loop`` (:meth:`pipelined_phase_fns` at depth
+        1).  Shared by the fused mode (composed under one jit), the traced
+        mode (jitted and fenced per phase), and the XLA cost estimator
+        (lowered per phase for abstract inputs).
         """
-        W = self.cfg.num_workers if workers is None else int(workers)
-        prep = self._prep_fn()
-        map_step = self._map_step_fn(W)
-        shuffle_step = self._shuffle_step_fn(
-            W if self.shuffle.collective else 1
-        )
-        reduce_step = self._reduce_step_fn(W)
-        map_waves = math.ceil(self.M / W)
-        red_waves = math.ceil(self.R / W)
-        init_map = self.initial_map_buffers
-        init_red = self.initial_reduce_buffers
-
-        def phase_map(tokens):
-            splits, valid = prep(tokens)
-
-            def body(i, bufs):
-                return map_step(splits, valid, *bufs, i * W)
-
-            return jax.lax.fori_loop(0, map_waves, body, init_map())
-
-        def phase_shuffle(bk, bv, bp):
-            pk, pv, dropped, _, _ = shuffle_step(bk, bv, bp)
-            return pk, pv, dropped
-
-        def phase_reduce(pk, pv):
-            def body(i, bufs):
-                return reduce_step(pk, pv, *bufs, i * W)
-
-            return jax.lax.fori_loop(
-                0, red_waves, body, init_red(pk.shape[1])
-            )
-
-        fns = {"map": phase_map}
-        if self.combiner:
-            fns["combine"] = self._combine_step_fn()
-        fns["shuffle"] = phase_shuffle
-        fns["reduce"] = phase_reduce
-        return {p: phases.scoped(p, f) for p, f in fns.items()}
+        return self.pipelined_phase_fns(workers, 1)
 
     # ---------------------------------------------------------------- modes
 
@@ -826,9 +752,28 @@ class ExecutionPlan:
              if depth is None else int(depth))
         progs = {p: _counted(self._counts[p], f)
                  for p, f in self.pipelined_phase_fns(workers, D).items()}
-        m = self.meta(workers)
-        pair_bytes = phases.PAIR_BYTES
+        return self._recording_job(recorder, progs, self.meta(workers), D)
+
+    def _recording_job(self, recorder, progs: dict, m: dict, depth: int = 1,
+                       every: dict | None = None,
+                       drops=lambda dropped: (dropped, {}, ()),
+                       collect=lambda ok, ov: (ok, ov)):
+        """The job of both fenced modes (:meth:`traced`, :meth:`sharded`
+        with a recorder): runs the :func:`_counted` phase programs
+        ``progs`` (the map's takes the job's tokens) one at a time, each
+        fenced and wall-clocked, and records each phase with its device
+        counters and the static facts ``m`` (:meth:`meta`) on a new trace
+        of ``recorder``.
+
+        ``every`` holds fields every phase record carries (a mesh's
+        ``workers``).  ``drops(dropped)`` turns the shuffle's drop counts
+        into the job's ``dropped``, the shuffle record's own fields, and a
+        tuple the job returns after its three outputs.  ``collect(ok,
+        ov)`` gives the reduce output as ``(R, cap)``.
+        """
+        every = every or {}
         app, cfg = self.app, self.cfg
+        pair_bytes = phases.PAIR_BYTES
 
         def job(tokens):
             trace = recorder.start_job(app.name, cfg, m["input_len"])
@@ -848,7 +793,7 @@ class ExecutionPlan:
             bufs, dt, cpu = _fenced("map", progs["map"], c, tokens)
             trace.record_phase(
                 "map", dt,
-                tasks=m["mappers"], waves=m["map_waves"],
+                tasks=m["mappers"], waves=m["map_waves"], **every,
                 records_in=c["map.records"],
                 pairs_emitted=c["map.pairs"], pairs_capacity=c["map.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
@@ -859,7 +804,7 @@ class ExecutionPlan:
                                         *bufs)
                 trace.record_phase(
                     "combine", dt,
-                    tasks=m["mappers"],
+                    tasks=m["mappers"], **every,
                     pairs_in=c["map.pairs"], pairs_out=c["combine.pairs"],
                     bytes_in=c["map.pairs"] * pair_bytes,
                     bytes_out=c["combine.pairs"] * pair_bytes,
@@ -874,6 +819,7 @@ class ExecutionPlan:
             (pk, pv, dropped), dt, cpu = _fenced(
                 "shuffle", progs["shuffle"], c, *bufs
             )
+            dropped, fields, tail = drops(dropped)
             pairs_in = c["shuffle.pairs"]
             trace.record_phase(
                 "shuffle", dt,
@@ -882,8 +828,11 @@ class ExecutionPlan:
                 bytes_in=pairs_in * pair_bytes,
                 bytes_out=c["reduce.pairs"] * pair_bytes,
                 bytes_dropped=c["shuffle.dropped"] * pair_bytes,
-                partitions=m["reducers"],
-                partition_capacity=int(pk.shape[1]),
+                partitions=m["reducers"], **every,
+                # The capacity the executed shuffle actually allocated
+                # (the sharded mode substitutes the collective for the
+                # configured shuffle).
+                partition_capacity=int(pk.shape[-1]), **fields,
                 cpu_s=cpu, cpu_workers=_NCPU,
                 # Fabric accounting: every pair entering the shuffle
                 # crosses the wire (dropped ones included) — post-combine
@@ -895,16 +844,17 @@ class ExecutionPlan:
 
             (ok, ov), dt, cpu = _fenced("reduce", progs["reduce"], c,
                                         pk, pv)
+            ok, ov = collect(ok, ov)
             trace.record_phase(
                 "reduce", dt,
-                tasks=m["reducers"], waves=m["reduce_waves"],
+                tasks=m["reducers"], waves=m["reduce_waves"], **every,
                 segments_out=c["reduce.segments"],
                 segment_slots=c["reduce.slots"],
                 cpu_s=cpu, cpu_workers=_NCPU,
             )
 
             total = _time.perf_counter() - t_job
-            if D > 1:
+            if depth > 1:
                 # Overlap happens *inside* the fenced map/reduce phases
                 # (their walls already absorb it), so the explicit
                 # pipeline phase carries only the cross-phase residual —
@@ -914,10 +864,10 @@ class ExecutionPlan:
                 residual = max(0.0, total - trace.phase_time_sum())
                 trace.record_phase(
                     "pipeline", residual,
-                    overlap_depth=D, net_bytes=0.0,
+                    overlap_depth=depth, net_bytes=0.0,
                 )
             trace.finish(total)
-            return ok, ov, dropped
+            return (ok, ov, dropped, *tail)
 
         return job
 
@@ -934,17 +884,20 @@ class ExecutionPlan:
 
     def sharded(self, mesh, axis: str = "workers", counters: bool = False,
                 recorder=None):
-        """Mode ``sharded``: ``shard_map`` around the same phase
-        primitives — workers are devices on ``mesh[axis]``, the shuffle a
-        literal ``all_to_all``.  This is the deployment path for real
+        """Mode ``sharded``: ``shard_map`` over the plan's own waves —
+        workers are devices on ``mesh[axis]``, the shuffle a literal
+        ``all_to_all``.  Worker w owns map tasks w, w+W, ... and reducers
+        w, w+W, ...; it runs its ``waves_m`` tasks and its ``waves_r``
+        partitions one at a time through the same compute/commit waves as
+        every other mode, and its combine through the same
+        ``combine_rows``.  This is the deployment path for real
         multi-chip meshes; semantics match every other mode.
 
         ``recorder=None`` compiles the fused single-program form (one
-        dispatch, zero overhead).  With a recorder, the three phases
-        compile as *separate* mesh programs so each can be fenced and
-        wall-clocked — per-phase wall times and measured counters on the
-        sharded path, which the fused ``shard_map`` program inherently
-        collapses to one aggregate.
+        dispatch, zero overhead).  With a recorder, the phases compile as
+        *separate* mesh programs, run and recorded by the same fenced
+        runner as :meth:`traced`'s; their records also carry ``workers``,
+        and the shuffle's ``dropped_send`` / ``dropped_recv``.
 
         With ``counters=True`` the returned job additionally yields a
         ``stats`` dict reducing the per-worker overflow counters across
@@ -955,7 +908,7 @@ class ExecutionPlan:
         (the input split with the map); the output gather after the
         reduce has none of its own.
         """
-        cfg, app = self.cfg, self.app
+        cfg = self.cfg
         W = mesh.shape[axis]
         if cfg.num_workers != W:
             raise ValueError(
@@ -965,19 +918,9 @@ class ExecutionPlan:
         if not shuffle.collective:
             # The sharded path's structural shuffle IS the mesh collective.
             shuffle = _backends.SHUFFLE_BACKENDS["all_to_all"]
-        reduce_backend = self.reduce_backend
-        M, R, P = self.M, self.R, self.P
-        input_len = self.input_len
+        R, P = self.R, self.P
         waves_m = cfg.map_waves
         waves_r = cfg.reduce_waves
-        M_pad = waves_m * W
-        n_local = waves_m * P
-        combiner = self.combiner
-        combine_cap = self.combine_cap
-        reduce_op = app.reduce_op
-        #: per-worker stream width entering the collective — the combine
-        #: contraction shrinks the literal all_to_all itself
-        n_local_c = waves_m * (combine_cap if combiner else P)
 
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P_
@@ -999,69 +942,55 @@ class ExecutionPlan:
             # Worker-major task layout: worker w owns tasks w, w+W, ...
             return tuple(
                 jnp.swapaxes(x.reshape(waves_m, W, *x.shape[1:]), 0, 1)
-                for x in self._split(tokens, M_pad)
+                for x in self._split(tokens, waves_m * W)
             )
 
-        def w_map(splits, valid):  # (1(worker), waves, ...) local shards
-            # Local map waves: reuse the shared map phase with W_local = 1.
-            sp = splits[0][:, None]   # (waves, 1, <one split>)
-            va = valid[0][:, None]
-            k, v, pv = map_phase(app, cfg, sp, va)
-            return (
-                k.reshape(1, n_local),
-                v.reshape(1, n_local),
-                pv.reshape(1, n_local),
-            )
+        # A worker's own task rows, one wave of one task at a time.
+        map_loop = self._serial_loop(
+            *self._map_wave(1, waves_m), waves_m, 1,
+            lambda splits, valid: _buffers(_MAP_FILLS, waves_m, P),
+        )
+        reduce_loop = self._serial_loop(
+            *self._reduce_wave(1, waves_r), waves_r, 1,
+            lambda bk, bv: _buffers(_REDUCE_FILLS, waves_r, bk.shape[1]),
+        )
+        combine = self._combine_step_fn()
 
-        def w_combine(k, v, pv):  # (1, n_local) local pair streams
-            # Shard-local map-side combine: this worker's waves_m task
-            # rows, aggregated + compacted before any byte crosses the
-            # mesh — the per-worker stream (and the collective built on
-            # it) shrinks from waves_m*P to waves_m*Pc.
-            ck, cv, cp = phases.combine_rows(
-                reduce_backend,
-                k[0].reshape(waves_m, P),
-                v[0].reshape(waves_m, P),
-                pv[0].reshape(waves_m, P),
-                reduce_op, combine_cap,
-            )
-            return (
-                ck.reshape(1, n_local_c),
-                cv.reshape(1, n_local_c),
-                cp.reshape(1, n_local_c),
-            )
+        # Local shards carry a leading worker axis of 1; between the
+        # phases they are (1, waves_m, width) task rows, and the reduce's
+        # (1, waves_r, cap) owned partitions.
+        def w_map(splits, valid):
+            return tuple(x[None] for x in map_loop(splits[0], valid[0]))
 
-        def w_shuffle(k, v, pv):  # (1, n_local[_c]) local pair streams
+        def w_combine(k, v, pv):
+            # Before any byte crosses the mesh: the collective built on
+            # this worker's stream shrinks from waves_m*P to waves_m*Pc.
+            return tuple(x[None] for x in combine(k[0], v[0], pv[0]))
+
+        def w_shuffle(k, v, pv):
             bk, bv, dropped = shuffle.exchange(
-                cfg, axis, k[0], v[0], pv[0]
+                cfg, axis, k.reshape(-1), v.reshape(-1), pv.reshape(-1)
             )
             return bk[None], bv[None], dropped[None]
 
-        def w_reduce(bk, bv):  # (1, waves_r, cap) owned reduce slots
-            ok, ov = reduce_local(app, cfg, bk[0], bv[0], reduce_backend)
-            return ok[None], ov[None]
+        def w_reduce(bk, bv):
+            return tuple(x[None] for x in reduce_loop(bk[0], bv[0]))
 
         prep = phases.scoped("map", prep)
         wfns = {"map": w_map}
-        if combiner:
+        if self.combiner:
             wfns["combine"] = w_combine
         wfns["shuffle"] = w_shuffle
         wfns["reduce"] = w_reduce
         wfns = {p: phases.scoped(p, f) for p, f in wfns.items()}
 
         def to_reducer_major(ok, ov):
-            # (W, waves_r, cap) -> (R, cap) indexed by reducer id: reducer
-            # r lives on worker r % W at local slot r // W, so row r of
-            # the slot-major stacking is exactly reducer r's partition.
             # Gather the worker shards first: folding the sharded worker
             # axis into the reducer axis has no sharding to carry on a
             # mesh with Explicit axes (jax.make_mesh's default).
             ok = jax.sharding.reshard(ok, replicated)
             ov = jax.sharding.reshard(ov, replicated)
-            cap = ok.shape[-1]
-            ok = ok.transpose(1, 0, 2).reshape(-1, cap)[:R]
-            ov = ov.transpose(1, 0, 2).reshape(-1, cap)[:R]
-            return ok, ov
+            return _reducer_major(ok, R), _reducer_major(ov, R)
 
         def stats_from(per_worker: np.ndarray) -> dict:
             return {
@@ -1117,11 +1046,10 @@ class ExecutionPlan:
 
         # Phase-fenced sharded execution: separate mesh programs, each
         # wall-clocked, its counters reduced across shards on the device.
-        pair_bytes = phases.PAIR_BYTES
         specs = {
-            "map": ((spec1, spec1), (spec2, spec2, spec2)),
-            "combine": ((spec2, spec2, spec2), (spec2, spec2, spec2)),
-            "shuffle": ((spec2, spec2, spec2), (spec3, spec3, spec2)),
+            "map": ((spec1, spec1), (spec3,) * 3),
+            "combine": ((spec3,) * 3, (spec3,) * 3),
+            "shuffle": ((spec3,) * 3, (spec3, spec3, spec2)),
             "reduce": ((spec3, spec3), (spec3, spec3)),
         }
         mesh_fns = {p: smap(f, *specs[p]) for p, f in wfns.items()}
@@ -1130,80 +1058,13 @@ class ExecutionPlan:
         progs = {p: _counted(self._counts[p], f)
                  for p, f in mesh_fns.items()}
 
-        def traced_job(tokens):
-            trace = recorder.start_job(app.name, cfg, input_len)
-            try:
-                return _run(tokens, trace)
-            except Exception:
-                if trace in recorder.traces:
-                    recorder.traces.remove(trace)
-                raise
+        def drops(dropped):
+            per_worker = np.asarray(dropped)  # (W, 2): [send, recv]
+            stats = stats_from(per_worker)
+            fields = {k: stats[k] for k in ("dropped_send", "dropped_recv")}
+            return per_worker.sum(), fields, (stats,) if counters else ()
 
-        def _run(tokens, trace):
-            t_job = _time.perf_counter()
-            c = {}
-
-            bufs, dt, cpu = _fenced("map", progs["map"], c, tokens)
-            trace.record_phase(
-                "map", dt,
-                tasks=M, waves=waves_m, workers=W,
-                records_in=c["map.records"],
-                pairs_emitted=c["map.pairs"], pairs_capacity=c["map.slots"],
-                cpu_s=cpu, cpu_workers=_NCPU,
-            )
-
-            if "combine" in progs:
-                bufs, dt, cpu = _fenced("combine", progs["combine"], c,
-                                        *bufs)
-                trace.record_phase(
-                    "combine", dt,
-                    tasks=M, workers=W,
-                    pairs_in=c["map.pairs"], pairs_out=c["combine.pairs"],
-                    bytes_in=c["map.pairs"] * pair_bytes,
-                    bytes_out=c["combine.pairs"] * pair_bytes,
-                    combine_capacity=combine_cap,
-                    cpu_s=cpu, cpu_workers=_NCPU,
-                    net_bytes=0.0,
-                )
-
-            (bk, bv, dropped), dt, cpu = _fenced(
-                "shuffle", progs["shuffle"], c, *bufs
-            )
-            per_worker = np.asarray(dropped)
-            pairs_in = c["shuffle.pairs"]
-            trace.record_phase(
-                "shuffle", dt,
-                pairs_in=pairs_in, pairs_out=c["reduce.pairs"],
-                pairs_dropped=c["shuffle.dropped"],
-                bytes_in=pairs_in * pair_bytes,
-                bytes_out=c["reduce.pairs"] * pair_bytes,
-                bytes_dropped=c["shuffle.dropped"] * pair_bytes,
-                partitions=R, workers=W,
-                # The capacity the executed exchange actually allocated
-                # (the configured shuffle may have been substituted by
-                # the collective on this path).
-                partition_capacity=int(bk.shape[-1]),
-                dropped_send=int(per_worker[:, 0].sum()),
-                dropped_recv=int(per_worker[:, 1].sum()),
-                cpu_s=cpu, cpu_workers=_NCPU,
-                net_bytes=pairs_in * pair_bytes,
-                net_s=dt,
-            )
-
-            (ok, ov), dt, cpu = _fenced("reduce", progs["reduce"], c,
-                                        bk, bv)
-            ok, ov = to_reducer_major(ok, ov)
-            trace.record_phase(
-                "reduce", dt,
-                tasks=R, waves=waves_r, workers=W,
-                segments_out=c["reduce.segments"],
-                segment_slots=c["reduce.slots"],
-                cpu_s=cpu, cpu_workers=_NCPU,
-            )
-
-            trace.finish(_time.perf_counter() - t_job)
-            if counters:
-                return ok, ov, per_worker.sum(), stats_from(per_worker)
-            return ok, ov, per_worker.sum()
-
-        return traced_job
+        return self._recording_job(
+            recorder, progs, self.meta(W), every={"workers": W},
+            drops=drops, collect=to_reducer_major,
+        )

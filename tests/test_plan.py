@@ -199,6 +199,16 @@ class TestShardedEquivalence:
         assert trace.check_conservation() == []
         assert trace.counter("map", "pairs_emitted") == len(CORPUS)
         assert trace.counter("shuffle", "dropped_send") == 0
+        # One runner records both fenced modes: the same phases and
+        # counters, and on the mesh only its named extras beside them.
+        plan_recorder = PhaseRecorder()
+        plan.traced(plan_recorder)(CORPUS)
+        mine = plan_recorder.last
+        assert mine.phase_names() == trace.phase_names()
+        extras = {"shuffle": {"workers", "dropped_send", "dropped_recv"}}
+        for got, want in zip(trace.phases, mine.phases):
+            assert set(got.counters) == set(want.counters) | extras.get(
+                got.phase, {"workers"}), got.phase
 
     def test_sharded_traced_counters_stats(self, mesh1):
         """recorder + counters=True compose: per-worker overflow stats

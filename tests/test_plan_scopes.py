@@ -90,11 +90,18 @@ def _instructions(hlo_text: str) -> dict[str, str]:
 
 
 def _job(plan, mode, **kw):
+    if mode == "sharded":
+        return plan.sharded(jax.make_mesh((1,), ("workers",)), **kw)
     return plan.fused(**kw) if mode == "fused" else plan.pipelined(
         depth=2, **kw)
 
 
-@pytest.mark.parametrize("mode", ["fused", "pipelined"])
+#: the module each mode's job compiles to
+MODULES = {"fused": "jit_job", "pipelined": "jit_job",
+           "sharded": "jit_plain"}
+
+
+@pytest.mark.parametrize("mode", ["fused", "pipelined", "sharded"])
 @pytest.mark.parametrize("combiner", [False, True])
 @pytest.mark.parametrize("app_name", ["wordcount", "exim", "uservisits",
                                       "exim-lane"])
@@ -110,7 +117,7 @@ def test_scope_map_covers_the_compiled_job(app_name, combiner, mode):
     assert len(scoped) >= 0.95 * len(work), (len(scoped), len(work))
     want = PHASES if combiner else PHASES - {"combine"}
     assert set(scopes.values()) - {sc.UNSCOPED} == want
-    assert sc.module_of(text) == "jit_job"
+    assert sc.module_of(text) == MODULES[mode]
 
 
 def test_scopes_are_metadata_only(monkeypatch):
@@ -233,12 +240,15 @@ def test_traced_phases_are_host_spans_on_the_profiler_clock(tmp_path):
 
 
 @pytest.mark.parametrize("combiner", [False, True])
-def test_sharded_counts_what_the_emulated_collective_counts(combiner):
+@pytest.mark.parametrize("app_name", ["wordcount", "exim", "exim-lane",
+                                      "uservisits"])
+def test_sharded_counts_what_the_emulated_collective_counts(app_name,
+                                                            combiner):
     """One worker on a mesh of one: the sharded job's summed device
     counters equal the fused job's over the same collective layout, in
     the fused mesh program and in the fenced one."""
     mesh = jax.make_mesh((1,), ("workers",))
-    plan, corpus = _plan("wordcount", combiner=combiner,
+    plan, corpus = _plan(app_name, combiner=combiner,
                          shuffle_backend="all_to_all")
     *_, stats = plan.fused(counters=True)(corpus)
     want = {k: int(v) for k, v in stats.items()}
